@@ -13,7 +13,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import shard_map
 from repro.configs.base import ArchConfig
 from repro.core import pipeline as pl
 from repro.core.partitioner import plan_stages
@@ -57,7 +56,7 @@ class HydraRunner:
         max_pos = self.hc.seq_len if self.cfg.rope == "learned" else 0
         params = pl.init_trial_params(self.cfg, eng, plan, key,
                                       dtype=self.hc.param_dtype,
-                                      max_pos=max_pos)
+                                      max_pos=max_pos, mesh=self.mesh)
         opt_state = self.optimizer.init(params)
         hparams = {
             "lr": jnp.asarray([t.lr for t in gang.trials], jnp.float32),
@@ -113,7 +112,8 @@ class HydraRunner:
             self.trace.span_end("gang", arch=gang.arch)
         return [TrialResult(spec=t, steps=n_steps,
                             train_loss=float(losses[i]),
-                            val_loss=float(val[i]))
+                            val_loss=float(val[i]),
+                            restarts=report.restarts)
                 for i, t in enumerate(gang.trials)]
 
     def evaluate(self, gang: GangPlan, params, hparams, step: int):
@@ -134,7 +134,7 @@ class HydraRunner:
                 loss_vec = jax.lax.pmean(loss_vec, ax)
             return loss_vec
 
-        fn = jax.jit(shard_map(inner, mesh=self.mesh,
+        fn = jax.jit(jax.shard_map(inner, mesh=self.mesh,
                                    in_specs=(pspecs, bspecs),
                                    out_specs=P(), check_vma=False))
         return np.asarray(fn(params, batch))

@@ -22,16 +22,15 @@ collective is explicit and visible to the roofline analyzer.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
-from repro.compat import shard_map
 from repro.configs.base import ArchConfig
 from repro.core.partitioner import StagePlan, plan_stages
 from repro.models import blocks as BLK
@@ -161,21 +160,44 @@ def trial_params_struct(cfg: ArchConfig, eng: EngineConfig, plan: StagePlan,
     return jax.tree_util.tree_map_with_path(fix, one)
 
 
+@functools.lru_cache(maxsize=32)
+def _trial_params_init(cfg: ArchConfig, eng: EngineConfig, plan: StagePlan,
+                       dtype, max_pos: int, mesh) -> Callable:
+    def init(key):
+        keys = jax.random.split(key, eng.n_trials)
+        params = jax.vmap(
+            lambda k: lm.init_params(cfg, k, dtype=dtype, max_pos=max_pos,
+                                     n_layers=plan.padded_layers))(keys)
+        vpad = eng.padded_vocab(cfg.vocab_size)
+        if vpad != cfg.vocab_size:
+            pad = vpad - cfg.vocab_size
+            params["embed"]["tok"] = jnp.pad(
+                params["embed"]["tok"], ((0, 0), (0, pad), (0, 0)))
+            if "head" in params:
+                params["head"] = jnp.pad(params["head"],
+                                         ((0, 0), (0, 0), (0, pad)))
+        return params
+
+    shardings = None
+    if mesh is not None:
+        shardings = jax.tree.map(lambda s: NamedSharding(mesh, s),
+                                 param_pspecs(cfg, eng),
+                                 is_leaf=lambda x: isinstance(x, P))
+    return jax.jit(init, out_shardings=shardings)
+
+
 def init_trial_params(cfg: ArchConfig, eng: EngineConfig, plan: StagePlan,
-                      key, dtype=jnp.float32, max_pos: int = 0):
-    """Materialize K trials' parameters (stacked on a leading K axis)."""
-    keys = jax.random.split(key, eng.n_trials)
-    params = jax.vmap(
-        lambda k: lm.init_params(cfg, k, dtype=dtype, max_pos=max_pos,
-                                 n_layers=plan.padded_layers))(keys)
-    vpad = eng.padded_vocab(cfg.vocab_size)
-    if vpad != cfg.vocab_size:
-        pad = vpad - cfg.vocab_size
-        params["embed"]["tok"] = jnp.pad(
-            params["embed"]["tok"], ((0, 0), (0, pad), (0, 0)))
-        if "head" in params:
-            params["head"] = jnp.pad(params["head"], ((0, 0), (0, 0), (0, pad)))
-    return params
+                      key, dtype=jnp.float32, max_pos: int = 0, mesh=None):
+    """Materialize K trials' parameters (stacked on a leading K axis).
+
+    One jitted program: XLA fuses each leaf's RNG, scale and cast, so every
+    leaf is written once in ``dtype`` — no fp32 copy and no per-op
+    temporaries beside it (a bf16 chatglm3-6b fills most of one 16 GB chip,
+    so an eager init would not fit). ``mesh`` writes each leaf straight
+    into its ``param_pspecs`` sharding instead of onto the default device.
+    """
+    return _trial_params_init(cfg, eng, plan, jnp.dtype(dtype), max_pos,
+                              mesh)(key)
 
 
 def param_pspecs(cfg: ArchConfig, eng: EngineConfig):
@@ -329,7 +351,6 @@ def make_layer_gather(cfg: ArchConfig, eng: EngineConfig):
     if not eng.fsdp:
         return None
     specs = param_pspecs(cfg, eng)["layers"]
-    use_barrier = compat.differentiable_optimization_barrier()
 
     def gather(p_layer):
         def one(spec, leaf):
@@ -342,12 +363,8 @@ def make_layer_gather(cfg: ArchConfig, eng: EngineConfig):
                     # pin the gather to the param dtype: without the barrier
                     # XLA commutes downstream fp32 converts across the gather
                     # (2× ICI traffic and full-leaf fp32 temps — see the
-                    # buffer-dump analysis in EXPERIMENTS.md §Perf). Old jax
-                    # can't differentiate the barrier — drop the pin there
-                    # (correctness over the perf hint).
-                    if use_barrier:
-                        out = lax.optimization_barrier(out)
-                    return out
+                    # buffer-dump analysis in EXPERIMENTS.md §Perf)
+                    return lax.optimization_barrier(out)
             return leaf
 
         return jax.tree.map(one, specs, p_layer,
@@ -560,7 +577,7 @@ def make_train_step(cfg: ArchConfig, opts: ModelOptions, eng: EngineConfig,
         metrics = {"loss": loss_vec, "grad_norm": gnorm}
         return params_new, opt_new, metrics
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(pspecs, ospecs, bspecs, P(), P()),
         out_specs=(pspecs, ospecs, {"loss": P(), "grad_norm": P()}),
@@ -645,33 +662,31 @@ def _check_paged_support(cfg: ArchConfig, eng: EngineConfig) -> None:
 
 
 def serve_cache_struct(cfg: ArchConfig, eng: EngineConfig,
-                       dry_run: bool = True):
-    """Global cache pytree (ShapeDtypeStructs) for the serving pipeline.
+                       dry_run: bool = True, mesh=None):
+    """Global cache pytree (ShapeDtypeStructs) for the serving pipeline;
+    ``dry_run=False`` gives the zero-filled cache itself, written straight
+    into its ``serve_cache_pspecs`` sharding over ``mesh`` when one is given
+    (the sharding the serve step returns it in, so the first call compiles
+    the same program as every later one).
 
     Dense layout: layer leaves (K, M, Lp, mb_global, ...) with Lp sharded
     over the stage axis; shared-site leaves (K, M, S*slots, mb_global, ...).
     Paged layout (``eng.paged``): one block *pool* per (trial, layer) shared
-    by every slot cell — leaves (K, Lp, n_blocks, block_size, h_kv, hd) with
-    the n_blocks axis sharded over the data/pod axes (each shard's rows
-    reach only its own pool slice, via local ids in the block tables).
+    by every slot cell — leaves (K, Lp, n_blocks, h_kv, block_size, hd),
+    head-major (``blocks.layer_cache_shape``), with the n_blocks axis
+    sharded over the data/pod axes (each shard's rows reach only its own
+    pool slice, via local ids in the block tables).
     """
     plan = plan_stages(cfg, eng.n_stages)
     if eng.paged:
         _check_paged_support(cfg, eng)
-        layers = {
-            "k": jax.ShapeDtypeStruct(
-                (eng.n_trials, plan.padded_layers, eng.n_blocks,
-                 eng.block_size, cfg.n_kv_heads, cfg.head_dim),
-                eng.cache_dtype),
-            "v": jax.ShapeDtypeStruct(
-                (eng.n_trials, plan.padded_layers, eng.n_blocks,
-                 eng.block_size, cfg.n_kv_heads, cfg.head_dim),
-                eng.cache_dtype),
-        }
+        one = BLK.layer_cache_shape(cfg, eng.n_blocks, 0, eng.cache_dtype,
+                                    block_size=eng.block_size)
+        layers = jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(
+                (eng.n_trials, plan.padded_layers) + s.shape, s.dtype), one)
         tree = {"layers": layers, "shared": None}
-        if dry_run:
-            return tree
-        return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), tree)
+        return tree if dry_run else _zeros(tree, cfg, eng, mesh)
     mb_global = eng.microbatch * (1 if eng.batch_replicated
                                   else eng.data_size * eng.pod_size)
     one = BLK.layer_cache_shape(cfg, mb_global, eng.max_seq, eng.cache_dtype)
@@ -688,9 +703,16 @@ def serve_cache_struct(cfg: ArchConfig, eng: EngineConfig,
                 (eng.n_trials, eng.cache_groups, n_slots) + s.shape,
                 s.dtype), s_one)
     tree = {"layers": layers, "shared": shared}
-    if dry_run:
-        return tree
-    return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), tree)
+    return tree if dry_run else _zeros(tree, cfg, eng, mesh)
+
+
+def _zeros(tree, cfg: ArchConfig, eng: EngineConfig, mesh):
+    if mesh is None:
+        return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), tree)
+    return jax.tree.map(
+        lambda s, p: jnp.zeros(s.shape, s.dtype,
+                               device=NamedSharding(mesh, p)),
+        tree, serve_cache_pspecs(cfg, eng))
 
 
 def serve_cache_pspecs(cfg: ArchConfig, eng: EngineConfig):
@@ -1062,7 +1084,7 @@ def make_serve_step(cfg: ArchConfig, opts: ModelOptions, eng: EngineConfig,
     def inner(params, cache, batch):
         return pipeline_serve(cfg, opts, eng, params, cache, batch, mode)
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(pspecs, cspecs, bspecs),
         out_specs=(cspecs, batch_ax, batch_ax),
@@ -1112,7 +1134,7 @@ def make_slot_reset(cfg: ArchConfig, eng: EngineConfig, mesh,
                 "shared": (jax.tree.map(zero, cache["shared"])
                            if cache["shared"] is not None else None)}
 
-    mapped = shard_map(inner, mesh=mesh, in_specs=(cspecs, mspec),
+    mapped = jax.shard_map(inner, mesh=mesh, in_specs=(cspecs, mspec),
                            out_specs=cspecs, check_vma=False)
     if not jit:
         return mapped
@@ -1145,8 +1167,8 @@ def make_transfer_kernels(cfg: ArchConfig, eng: EngineConfig, mesh,
     of every layer's pool leaf, so one call moves all layers.
 
     **extract(cache, k, shard, local_ids)** — device → host: read trial k /
-    shard's pool blocks out to one host payload per id (a (2, Lp,
-    block_size, h_kv, hd) array stacking K and V). Read-only — extracting a
+    shard's pool blocks out to one host payload per id (a (2, Lp, h_kv,
+    block_size, hd) array stacking K and V). Read-only — extracting a
     shared block is always safe — and eager: spill/retract callers free the
     device block immediately after.
 
@@ -1165,7 +1187,7 @@ def make_transfer_kernels(cfg: ArchConfig, eng: EngineConfig, mesh,
     def inner(cache, src, dst):
         s, d = src[:, 0], dst[:, 0]  # local shard: (K, n_copies)
 
-        def upd(buf):  # (K, Lp_local, nb_local, bs, h_kv, hd)
+        def upd(buf):  # (K, Lp_local, nb_local, h_kv, bs, hd)
             nb = buf.shape[2]
 
             def one(bufk, sk, dk):
@@ -1177,8 +1199,9 @@ def make_transfer_kernels(cfg: ArchConfig, eng: EngineConfig, mesh,
 
         return {"layers": jax.tree.map(upd, cache["layers"]), "shared": None}
 
-    mapped = shard_map(inner, mesh=mesh, in_specs=(cspecs, ispec, ispec),
-                       out_specs=cspecs, check_vma=False)
+    mapped = jax.shard_map(inner, mesh=mesh,
+                           in_specs=(cspecs, ispec, ispec),
+                           out_specs=cspecs, check_vma=False)
     copy_fn = jax.jit(mapped, donate_argnums=(0,)) if jit else mapped
 
     dp = 1 if eng.batch_replicated else eng.data_size * eng.pod_size
@@ -1191,7 +1214,7 @@ def make_transfer_kernels(cfg: ArchConfig, eng: EngineConfig, mesh,
     def extract(cache, k, shard, local_ids):
         gids = _gids(shard, local_ids)
         # advanced indices (k, gids) split by the layer slice: result is
-        # (n, Lp, block_size, h_kv, hd)
+        # (n, Lp, h_kv, block_size, hd)
         kv = np.asarray(cache["layers"]["k"][k, :, gids])
         vv = np.asarray(cache["layers"]["v"][k, :, gids])
         return [np.stack([kv[j], vv[j]]) for j in range(len(local_ids))]

@@ -22,6 +22,8 @@ class TrialResult:
     steps: int
     train_loss: float
     val_loss: float
+    restarts: int = 0  # checkpoint restarts its gang's loop took (a restart
+    # recovers from a failed step, so a clean run reports 0)
 
 
 def grid_search(arch: str, lrs: Sequence[float],

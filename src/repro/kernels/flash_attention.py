@@ -21,11 +21,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-try:  # TPU-specific memory spaces; available (and interpretable) on CPU too
-    from jax.experimental.pallas import tpu as pltpu
-    VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover - very old jax
-    VMEM = lambda shape, dtype: pl.BlockSpec(memory_space=None)  # noqa: E731
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -121,9 +117,9 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True, window: int = 0,
         out_specs=pl.BlockSpec((1, block_q, hd), lambda i, j, t: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq_p, hd), q.dtype),
         scratch_shapes=[
-            VMEM((block_q,), jnp.float32),   # running max m
-            VMEM((block_q,), jnp.float32),   # running denom l
-            VMEM((block_q, hd), jnp.float32),  # output accumulator
+            pltpu.VMEM((block_q,), jnp.float32),   # running max m
+            pltpu.VMEM((block_q,), jnp.float32),   # running denom l
+            pltpu.VMEM((block_q, hd), jnp.float32),  # output accumulator
         ],
         interpret=interpret,
     )(q, k, v)

@@ -21,11 +21,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _scan_kernel(h0_ref, da_ref, dbx_ref, c_ref, y_ref, hout_ref, h_ref, *,
@@ -99,7 +95,7 @@ def mamba_scan_bdn(da, dbx, cmat, h0, *, chunk: int = 128,
             jax.ShapeDtypeStruct((b, s_p, di), da.dtype),
             jax.ShapeDtypeStruct((b, di, n), jnp.float32),
         ],
-        scratch_shapes=[VMEM((block_di, n), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_di, n), jnp.float32)],
         interpret=interpret,
     )(h0, da, dbx, cmat)
     return y[:, :s], h_out

@@ -1,11 +1,11 @@
 """Jitted dispatch wrappers for the Pallas kernels.
 
 Model code calls these (via ``ModelOptions.use_flash_kernel`` /
-``use_mamba_kernel`` / ``use_paged_kernel``); on this CPU container they run
-in interpret mode (kernel body executed in Python) — the TPU target compiles
-the same pl.pallas_call. Set ``REPRO_PALLAS_INTERPRET=0`` on real TPU.
-``paged_attention`` has its own three-way lowering switch
-(``REPRO_PAGED_ATTN``) — see the paged section below.
+``use_mamba_kernel`` / ``use_paged_kernel``). On a TPU backend every
+pl.pallas_call is compiled by Mosaic; on any other backend (the CPU test
+suite) it runs in interpret mode (kernel body executed in Python). Nothing
+picks interpret mode on a TPU. ``paged_attention`` has its own three-way
+lowering switch (``REPRO_PAGED_ATTN``) — see the paged section below.
 """
 from __future__ import annotations
 
@@ -21,10 +21,8 @@ from repro.kernels import paged_attention as pa
 
 
 def _interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() == "cpu"
+    """Interpret off-TPU only: a TPU backend always compiles the kernel."""
+    return jax.default_backend() != "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -86,7 +84,8 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
 # ---------------------------------------------------------------------------
 # paged attention: forward-only (serving decode/append — no AD path needed)
 # attention straight from the block pool through per-row block tables. Three
-# lowerings, picked by REPRO_PAGED_ATTN or the backend:
+# lowerings, picked by REPRO_PAGED_ATTN or else by the backend ("pallas" on
+# TPU, "jnp" elsewhere):
 #   "pallas"    — compiled Pallas kernel (blockspec variant), the TPU target.
 #   "interpret" — the Pallas kernel in interpret mode (loop variant); what
 #                 the tier-1 parity tests and forced engine parity runs use.
@@ -103,15 +102,13 @@ def _paged_mode() -> str:
     env = os.environ.get("REPRO_PAGED_ATTN")
     if env in ("pallas", "interpret", "jnp"):
         return env
-    if pa.PrefetchScalarGridSpec is None:  # pragma: no cover - very old jax
-        return "jnp"
-    return "jnp" if jax.default_backend() == "cpu" else "pallas"
+    return "pallas" if jax.default_backend() == "tpu" else "jnp"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window"))
 def paged_attention(q, k_pool, v_pool, block_tables, kv_offset, kv_len, *,
                     causal: bool = True, window: int = 0, q_lens=None):
-    """q (b, sq, hq, hd); k/v pool (n_blocks, block_size, hkv, hd);
+    """q (b, sq, hq, hd); k/v pool (n_blocks, hkv, block_size, hd);
     block_tables (b, n_tbl) int32 (-1 = unallocated); kv_offset/kv_len (b,)
     per-row cache depth / live length. ``q_lens (b,)`` (optional) is each
     row's real query count in a mixed ragged wave — padded positions emit

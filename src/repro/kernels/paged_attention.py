@@ -2,7 +2,8 @@
 block pool — no gathered logical K/V view.
 
 The serve stack's paged path (``blocks.paged_kv_update``) scatters new K/V
-into a shared ``(n_blocks, block_size, h_kv, hd)`` pool and then *gathers*
+into a shared head-major ``(n_blocks, h_kv, block_size, hd)`` pool and then
+*gathers*
 each row's full ``max_blocks * block_size`` logical view before running
 dense attention — O(max_seq) HBM traffic per decode step regardless of the
 row's actual ``kv_len``. This kernel removes the gather: attention reads K/V
@@ -17,14 +18,19 @@ Layout & grid
 
     * ``variant="blockspec"`` — grid ``(b, h_kv, n_tbl)`` with the table axis
       innermost *sequential* and (m, l, acc) in VMEM scratch; the K/V
-      BlockSpec index maps stream one physical ``(block_size, hd)`` block
+      BlockSpec index maps stream one physical ``(block_size, hd)`` tile
       into VMEM per step. This is the TPU compile target: the pool
       indirection is resolved by the pipeline before each body runs, so it
-      costs index arithmetic, not a gathered copy.
+      costs index arithmetic, not a gathered copy. The head-major pool puts
+      ``(block_size, hd)`` in the two minor dims, so each streamed tile is
+      whole in the dims Mosaic tiles by (8, 128) — a ``(block_size, h_kv,
+      hd)`` pool would make the block one head out of the second-minor dim,
+      which the TPU lowering refuses.
     * ``variant="loop"`` — grid ``(b, h_kv)`` with the whole pool left in
       ``ANY`` memory and an in-kernel ``fori_loop`` from the first windowed
-      block to ``ceil(kv_len / block_size)``, loading each live physical
-      block by table entry. This is the interpret-mode/CPU execution path
+      block to ``ceil(kv_len / block_size)``, reading each live physical
+      block by table entry (ref indexing). This is the interpret-mode/CPU
+      execution path
       (far fewer grid steps; per-row cost scales with live length and is
       flat in table width). On TPU the same structure needs the loads
       replaced by double-buffered ``make_async_copy`` — the noted next step.
@@ -67,14 +73,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-try:  # TPU-specific grid/memory spaces; interpretable on CPU too
-    from jax.experimental.pallas import tpu as pltpu
-    VMEM = pltpu.VMEM
-    PrefetchScalarGridSpec = pltpu.PrefetchScalarGridSpec
-except Exception:  # pragma: no cover - very old jax
-    pltpu = None
-    VMEM = None
-    PrefetchScalarGridSpec = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -138,8 +137,8 @@ def _paged_kernel(tbl_ref, off_ref, len_ref, ql_ref, q_ref, k_ref, v_ref,
     def _accum():
         m_ref[...], l_ref[...], acc_ref[...] = _accumulate(
             q_ref[0, 0].astype(jnp.float32),
-            k_ref[0, :, 0].astype(jnp.float32),
-            v_ref[0, :, 0].astype(jnp.float32),
+            k_ref[0, 0].astype(jnp.float32),
+            v_ref[0, 0].astype(jnp.float32),
             t, off, kv_end, q_len, m_ref[...], l_ref[...], acc_ref[...],
             scale=scale, causal=causal, window=window, block_size=block_size,
             sq_real=sq_real, rows_real=rows_real)
@@ -165,10 +164,9 @@ def _paged_kernel_loop(tbl_ref, off_ref, len_ref, ql_ref, q_ref, k_ref,
     def body(t, carry):
         m, l, acc = carry
         phys = jnp.maximum(tbl_ref[ib, t], 0)
-        k = pl.load(k_ref, (phys, slice(None), ih, slice(None)))
-        v = pl.load(v_ref, (phys, slice(None), ih, slice(None)))
         return _accumulate(
-            q, k.astype(jnp.float32), v.astype(jnp.float32),
+            q, k_ref[phys, ih].astype(jnp.float32),
+            v_ref[phys, ih].astype(jnp.float32),
             t, off, kv_end, q_len, m, l, acc, scale=scale, causal=causal,
             window=window, block_size=block_size, sq_real=sq_real,
             rows_real=rows_real)
@@ -190,8 +188,8 @@ def paged_attention_pool(q, k_pool, v_pool, block_tables, kv_offset, kv_len,
                          *, causal: bool = True, window: int = 0,
                          interpret: bool = False, variant: str | None = None,
                          q_lens=None):
-    """Core pallas_call. q (b, sq, hq, hd); k/v pool (n_blocks, block_size,
-    h_kv, hd); block_tables (b, n_tbl) int32 physical ids (-1 unallocated);
+    """Core pallas_call. q (b, sq, hq, hd); k/v pool (n_blocks, h_kv,
+    block_size, hd); block_tables (b, n_tbl) int32 physical ids (-1 unallocated);
     kv_offset/kv_len (b,) int32. Returns (b, sq, hq, hd).
 
     ``q_lens (b,)`` (optional) gives each row's real query count for mixed
@@ -208,7 +206,7 @@ def paged_attention_pool(q, k_pool, v_pool, block_tables, kv_offset, kv_len,
     b, sq, hq, hd = q.shape
     if q_lens is None:
         q_lens = jnp.full((b,), sq, jnp.int32)
-    nb, bs, hkv, _ = k_pool.shape
+    nb, hkv, bs, _ = k_pool.shape
     n_tbl = block_tables.shape[1]
     g = hq // hkv
     assert hq == hkv * g, (hq, hkv)
@@ -226,14 +224,14 @@ def paged_attention_pool(q, k_pool, v_pool, block_tables, kv_offset, kv_len,
     if variant == "loop":
         kernel = functools.partial(_paged_kernel_loop, rows=rows, hd=hd,
                                    **common)
-        grid_spec = PrefetchScalarGridSpec(
+        grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(b, hkv),
             in_specs=[
                 pl.BlockSpec((1, 1, rows, hd),
                              lambda ib, ih, tbl, off, ln, ql: (ib, ih, 0, 0)),
-                pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec(memory_space=pltpu.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec((1, 1, rows, hd),
                                    lambda ib, ih, tbl, off, ln, ql:
@@ -241,7 +239,7 @@ def paged_attention_pool(q, k_pool, v_pool, block_tables, kv_offset, kv_len,
         )
     else:
         kernel = functools.partial(_paged_kernel, n_tbl=n_tbl, **common)
-        grid_spec = PrefetchScalarGridSpec(
+        grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(b, hkv, n_tbl),
             in_specs=[
@@ -251,20 +249,20 @@ def paged_attention_pool(q, k_pool, v_pool, block_tables, kv_offset, kv_len,
                 # the pool indirection: table entry t of row ib names the
                 # physical block streamed at grid step (ib, ih, t); -1 clamps
                 # to block 0 (its positions are masked via kv_len)
-                pl.BlockSpec((1, bs, 1, hd),
+                pl.BlockSpec((1, 1, bs, hd),
                              lambda ib, ih, t, tbl, off, ln, ql:
-                             (jnp.maximum(tbl[ib, t], 0), 0, ih, 0)),
-                pl.BlockSpec((1, bs, 1, hd),
+                             (jnp.maximum(tbl[ib, t], 0), ih, 0, 0)),
+                pl.BlockSpec((1, 1, bs, hd),
                              lambda ib, ih, t, tbl, off, ln, ql:
-                             (jnp.maximum(tbl[ib, t], 0), 0, ih, 0)),
+                             (jnp.maximum(tbl[ib, t], 0), ih, 0, 0)),
             ],
             out_specs=pl.BlockSpec((1, 1, rows, hd),
                                    lambda ib, ih, t, tbl, off, ln, ql:
                                    (ib, ih, 0, 0)),
             scratch_shapes=[
-                VMEM((rows,), jnp.float32),      # running max m
-                VMEM((rows,), jnp.float32),      # running denom l
-                VMEM((rows, hd), jnp.float32),   # output accumulator
+                pltpu.VMEM((rows,), jnp.float32),     # running max m
+                pltpu.VMEM((rows,), jnp.float32),     # running denom l
+                pltpu.VMEM((rows, hd), jnp.float32),  # output accumulator
             ],
         )
     out = pl.pallas_call(

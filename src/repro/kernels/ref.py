@@ -23,19 +23,24 @@ def paged_attention_ref(q, k_pool, v_pool, block_tables, kv_offset, kv_len,
                         *, causal=True, window=0, q_lens=None):
     """Gather-then-attend oracle for the paged kernel (fp32 math).
 
-    Materializes each row's full logical K/V view through its block table
-    (the exact path ``blocks.paged_kv_update`` takes) and runs the direct-
-    softmax reference over it — the kernel must match this on live
+    k/v pool (n_blocks, h_kv, block_size, hd), head-major as the serve
+    cache holds it. Materializes each row's full logical K/V view through
+    its block table (the exact path ``blocks.paged_kv_update`` takes) and
+    runs the direct-softmax reference over it — the kernel must match this on live
     positions while never building the gathered view. ``q_lens (b,)``
     mirrors the kernel's ragged-wave semantics: query positions past a
     row's real count are zeroed.
     """
-    nb, bs = k_pool.shape[0], k_pool.shape[1]
+    nb, hkv, bs, hd = k_pool.shape
     b = q.shape[0]
     span = (jnp.clip(block_tables, 0, nb - 1)[:, :, None] * bs
             + jnp.arange(bs)[None, None, :]).reshape(b, -1)
-    kf = jnp.take(k_pool.reshape(nb * bs, *k_pool.shape[2:]), span, axis=0)
-    vf = jnp.take(v_pool.reshape(nb * bs, *v_pool.shape[2:]), span, axis=0)
+
+    def token_major(pool):  # (nb, hkv, bs, hd) -> (nb * bs, hkv, hd)
+        return pool.transpose(0, 2, 1, 3).reshape(nb * bs, hkv, hd)
+
+    kf = jnp.take(token_major(k_pool), span, axis=0)
+    vf = jnp.take(token_major(v_pool), span, axis=0)
     out = attention_reference(q.astype(jnp.float32), kf.astype(jnp.float32),
                               vf.astype(jnp.float32), causal=causal,
                               window=window, kv_offset=kv_offset,

@@ -6,7 +6,15 @@ XLA_FLAGS before any jax initialization.
 """
 from __future__ import annotations
 
-from repro.compat import make_mesh
+import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(axis_shapes, axis_names):
+    """``jax.make_mesh`` with Auto axes: the engine's programs are explicit
+    shard_maps, so no axis is in Explicit sharding mode (jax's default)."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

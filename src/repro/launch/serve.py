@@ -43,15 +43,19 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh
 
 from repro.configs import get_config
+from repro.configs.base import ArchConfig
 from repro.core import pipeline as pl
 from repro.core import scheduler as sched
 from repro.core.partitioner import plan_stages
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_test_mesh
 from repro.models.layers import ModelOptions
 from repro.obs import (Tracer, report, write_events, write_metrics,
@@ -194,8 +198,8 @@ def parse_weights(spec: str, k: int):
     return w
 
 
-def main():
-    args = build_args().parse_args()
+def check_args(args) -> None:
+    """Reject flag combinations no engine can run (before any compile)."""
     if args.paged and args.static:
         raise SystemExit("--static is the dense lockstep baseline; "
                          "drop --paged")
@@ -228,17 +232,46 @@ def main():
                          "round's ragged call structure; pick one")
     if args.spec_draft and args.spec_gamma < 1:
         raise SystemExit(f"--spec-gamma must be >= 1, got {args.spec_gamma}")
+
+
+@dataclasses.dataclass
+class ServeSetup:
+    """Everything one serving run is built from (see :func:`build_serving`)."""
+
+    cfg: ArchConfig
+    eng: pl.EngineConfig
+    mesh: Mesh
+    opts: ModelOptions
+    params: dict
+    requests: list
+    spec_pairs: Optional[dict]
+
+
+def build_serving(args, requests=None) -> ServeSetup:
+    """Parsed CLI args -> mesh, config, engine config (capacity-planned when
+    ``--slots 0``), request stream and trial-stacked weights.
+
+    ``requests`` (optional) replaces the ``--trace`` / synthetic stream; it
+    is checked against ``max_seq`` the same way. Published-width models
+    hold weights, KV cache and activations in bf16, their published dtype;
+    ``--smoke`` keeps fp32, which the CPU oracle tests compare bit-exactly.
+    Weights are made after every check, so a bad trace fails before any
+    compile.
+    """
+    check_args(args)
     weights = parse_weights(args.arch_weights, args.arches)
     mesh = make_test_mesh(args.n_data, args.n_model)
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.reduced()
+    dtype = jnp.float32 if args.smoke else jnp.bfloat16
     max_seq = args.prompt_len + args.gen_len
-    opts = ModelOptions(use_paged_kernel=args.paged_kernel)
+    opts = ModelOptions(use_paged_kernel=args.paged_kernel,
+                        param_dtype=dtype, compute_dtype=dtype)
     base = pl.EngineConfig(
         n_trials=args.arches, n_microbatches=max(args.slots, 1),
         microbatch=args.microbatch, n_stages=args.n_model,
-        data_size=args.n_data, max_seq=max_seq, cache_dtype=jnp.float32,
+        data_size=args.n_data, max_seq=max_seq, cache_dtype=dtype,
         prefill_chunks=args.prefill_chunks, paged=args.paged,
         block_size=args.block_size, window=args.window)
     if args.slots <= 0:
@@ -283,7 +316,7 @@ def main():
         def skeleton(c):
             shapes = jax.eval_shape(lambda: pl.init_trial_params(
                 c, e1, plan_stages(c, eng.n_stages), jax.random.PRNGKey(0),
-                max_pos=max_seq))
+                dtype=dtype, max_pos=max_seq))
             return jax.tree.map(lambda x: (x.shape, x.dtype), shapes)
 
         if dcfg.vocab_size != cfg.vocab_size or skeleton(dcfg) != skeleton(cfg):
@@ -297,8 +330,9 @@ def main():
         spec_pairs = {k: args.arches + k for k in range(args.arches)}
         eng = dataclasses.replace(eng, n_trials=2 * args.arches)
 
-    if args.trace:
+    if requests is None and args.trace:
         requests = load_trace(args.trace)
+    if requests is not None:
         too_long = [r.rid for r in requests if r.total_len > max_seq]
         if too_long:
             raise SystemExit(f"trace requests {too_long} exceed max_seq="
@@ -337,26 +371,39 @@ def main():
     plan = plan_stages(cfg, eng.n_stages)
     params = pl.init_trial_params(cfg, eng, plan,
                                   jax.random.PRNGKey(args.seed),
-                                  max_pos=max_seq)
+                                  dtype=dtype, max_pos=max_seq, mesh=mesh)
+    return ServeSetup(cfg, eng, mesh, opts, params, requests, spec_pairs)
 
+
+def make_engine(args, setup: ServeSetup, opts: Optional[ModelOptions] = None,
+                tracer=None) -> ServeEngine:
+    """The continuous engine the CLI flags describe, over ``setup``'s
+    weights; ``opts`` (optional) replaces ``setup.opts``."""
+    return ServeEngine(setup.cfg, setup.eng, setup.mesh, setup.params,
+                       opts or setup.opts, overcommit=args.overcommit,
+                       policy=args.policy, prefix_cache=args.prefix_cache,
+                       spill=not args.no_spill, fused=args.fused_admission,
+                       spec_gamma=args.spec_gamma if args.spec_draft else 0,
+                       spec_pairs=setup.spec_pairs, tracer=tracer)
+
+
+def main():
+    args = build_args().parse_args()
     tracing = bool(args.trace_out or args.events_out)
     if tracing and args.static:
         raise SystemExit("--trace-out/--events-out trace the continuous "
                          "engine's rounds; drop --static")
+    enable_compile_cache()
+    setup = build_serving(args)
+    cfg, eng, requests = setup.cfg, setup.eng, setup.requests
     tracer = Tracer() if tracing else None
 
     if args.static:
-        completions, stats = static_serve(cfg, eng, mesh, params, requests,
-                                          opts)
+        completions, stats = static_serve(cfg, eng, setup.mesh, setup.params,
+                                          requests, setup.opts)
         mode = "static"
     else:
-        engine = ServeEngine(cfg, eng, mesh, params, opts,
-                             overcommit=args.overcommit, policy=args.policy,
-                             prefix_cache=args.prefix_cache,
-                             spill=not args.no_spill,
-                             fused=args.fused_admission,
-                             spec_gamma=args.spec_gamma if args.spec_draft
-                             else 0, spec_pairs=spec_pairs, tracer=tracer)
+        engine = make_engine(args, setup, tracer=tracer)
         completions = engine.run(requests)
         stats = engine.stats
         mode = "continuous/paged" if args.paged else "continuous"

@@ -18,6 +18,7 @@ from repro.configs import REGISTRY
 from repro.core import pipeline as pl
 from repro.core.hydra import HydraConfig, run_model_selection
 from repro.core.trials import SuccessiveHalving, grid_search
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_test_mesh
 from repro.models.layers import ModelOptions
 
@@ -39,6 +40,7 @@ def main():
                     help="successive halving instead of full grid")
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     n_needed = args.n_data * args.n_model
     if jax.device_count() < n_needed:

@@ -31,11 +31,13 @@ from repro.models.layers import ModelOptions
 def paged_kv_scatter(cache, k, v, block_tables, kv_offset, write_mask=None):
     """Scatter a (b, s) chunk of new K/V into the shared block pool.
 
-    cache {'k','v'}: (n_blocks, block_size, h_kv, hd) — the *pool*, shared by
-    every row (no batch axis). block_tables (b, max_blocks) int32 physical ids
-    local to this shard's pool slice, -1 = unallocated. kv_offset (b,) is the
-    row's cache depth (tokens already written). ``write_mask`` is (b,) rows
-    or (b, s) per-token (mixed ragged waves mask each row's padded tail).
+    cache {'k','v'}: (n_blocks, h_kv, block_size, hd) — the *pool*, shared
+    by every row (no batch axis), head-major so one (block, head) tile is a
+    whole ``(block_size, hd)`` slab (what the TPU paged kernel streams).
+    block_tables (b, max_blocks) int32 physical ids local to this shard's
+    pool slice, -1 = unallocated. kv_offset (b,) is the row's cache depth
+    (tokens already written). ``write_mask`` is (b,) rows or (b, s)
+    per-token (mixed ragged waves mask each row's padded tail).
     Masked entries — idle cells riding along, pipeline bubble ticks, or
     ragged query padding — write nothing
     (their scatter indices are pushed out of bounds and dropped); the
@@ -47,10 +49,8 @@ def paged_kv_scatter(cache, k, v, block_tables, kv_offset, write_mask=None):
     silently corrupt cached K/V. Returns the updated pool.
     """
     b, s = k.shape[0], k.shape[1]
-    nb, bs = cache["k"].shape[0], cache["k"].shape[1]
+    nb, bs = cache["k"].shape[0], cache["k"].shape[2]
     max_blocks = block_tables.shape[1]
-    pool_k = cache["k"].reshape(nb * bs, *cache["k"].shape[2:])
-    pool_v = cache["v"].reshape(nb * bs, *cache["v"].shape[2:])
     # scatter the chunk: token i of row r lands in block table[r, p//bs] at
     # in-block slot p%bs, p = kv_offset[r] + i
     pos = kv_offset[:, None] + jnp.arange(s)[None, :]  # (b, s)
@@ -59,13 +59,14 @@ def paged_kv_scatter(cache, k, v, block_tables, kv_offset, write_mask=None):
     ok = (phys >= 0) & (pos // bs < max_blocks)
     if write_mask is not None:
         ok = ok & (write_mask if write_mask.ndim == 2 else write_mask[:, None])
-    flat = jnp.where(ok, phys * bs + pos % bs, nb * bs)  # OOB -> dropped
-    pool_k = pool_k.at[flat.reshape(-1)].set(
-        k.reshape(b * s, *k.shape[2:]).astype(pool_k.dtype), mode="drop")
-    pool_v = pool_v.at[flat.reshape(-1)].set(
-        v.reshape(b * s, *v.shape[2:]).astype(pool_v.dtype), mode="drop")
-    return {"k": pool_k.reshape(cache["k"].shape),
-            "v": pool_v.reshape(cache["v"].shape)}
+    phys = jnp.where(ok, phys, nb).reshape(-1)  # OOB block -> dropped
+    slot = (pos % bs).reshape(-1)
+
+    def scat(pool, t):  # t (b, s, h_kv, hd) -> pool[phys, :, slot]
+        return pool.at[phys, :, slot].set(
+            t.reshape(b * s, *t.shape[2:]).astype(pool.dtype), mode="drop")
+
+    return {"k": scat(cache["k"], k), "v": scat(cache["v"], v)}
 
 
 def paged_kv_update(cache, k, v, block_tables, kv_offset, write_mask=None):
@@ -80,18 +81,18 @@ def paged_kv_update(cache, k, v, block_tables, kv_offset, write_mask=None):
     attends straight from the pool.
     """
     b = k.shape[0]
-    nb, bs = cache["k"].shape[0], cache["k"].shape[1]
+    nb, hkv, bs, hd = cache["k"].shape
     max_blocks = block_tables.shape[1]
     new_cache = paged_kv_scatter(cache, k, v, block_tables, kv_offset,
                                  write_mask)
-    pool_k = new_cache["k"].reshape(nb * bs, *cache["k"].shape[2:])
-    pool_v = new_cache["v"].reshape(nb * bs, *cache["v"].shape[2:])
     # gather each row's logical view: position j reads block table[r, j//bs]
-    span = (jnp.clip(block_tables, 0, nb - 1)[:, :, None] * bs
-            + jnp.arange(bs)[None, None, :]).reshape(b, max_blocks * bs)
-    k_rows = jnp.take(pool_k, span, axis=0)
-    v_rows = jnp.take(pool_v, span, axis=0)
-    return new_cache, k_rows, v_rows
+    phys = jnp.clip(block_tables, 0, nb - 1)
+
+    def gather(pool):  # (b, max_blocks, h_kv, bs, hd) -> token-major rows
+        return (jnp.take(pool, phys, axis=0).transpose(0, 1, 3, 2, 4)
+                .reshape(b, max_blocks * bs, hkv, hd))
+
+    return new_cache, gather(new_cache["k"]), gather(new_cache["v"])
 
 
 def attn_apply(cfg: ArchConfig, opts: ModelOptions, p, x, *, pos,
@@ -102,7 +103,7 @@ def attn_apply(cfg: ArchConfig, opts: ModelOptions, p, x, *, pos,
 
     ``block_tables`` switches the append/decode cache handling to the paged
     pool layout (see :func:`paged_kv_update`): cache is then the shared
-    (n_blocks, block_size, h_kv, hd) pool and ``write_mask`` gates which rows
+    (n_blocks, h_kv, block_size, hd) pool and ``write_mask`` gates which rows
     may write this call.
 
     ``q_lens (b,)`` activates the mixed-tick ragged-wave semantics in append
@@ -139,7 +140,7 @@ def attn_apply(cfg: ArchConfig, opts: ModelOptions, p, x, *, pos,
     elif mode == "append" and block_tables is not None:
         # paged chunked prefill: same semantics as the dense append below but
         # K/V live in the shared block pool, reached through per-row tables
-        cap = block_tables.shape[1] * cache["k"].shape[1]
+        cap = block_tables.shape[1] * cache["k"].shape[2]
         kv_len = jnp.minimum(kv_offset + (s if q_lens is None else q_lens),
                              cap)
         wm = write_mask
@@ -211,7 +212,7 @@ def attn_apply(cfg: ArchConfig, opts: ModelOptions, p, x, *, pos,
         # masked-full-cache attention the dense decode runs; window > 0
         # additionally masks positions <= pos - window (the gathered view is
         # in absolute logical layout, so the positional mask is exact)
-        cap = block_tables.shape[1] * cache["k"].shape[1]
+        cap = block_tables.shape[1] * cache["k"].shape[2]
         kv_len = jnp.minimum(kv_offset + 1, cap)
         if opts.use_paged_kernel:
             # kernel decode is causal with per-row offsets: at sq=1 the mask
@@ -380,8 +381,17 @@ def block_fn_for(cfg: ArchConfig):
 
 
 def layer_cache_shape(cfg: ArchConfig, batch: int, max_seq: int,
-                      cache_dtype=jnp.bfloat16) -> dict:
-    """Shape/dtype template for ONE layer's cache (no leading layer dim)."""
+                      cache_dtype=jnp.bfloat16, block_size: int = 0) -> dict:
+    """Shape/dtype template for ONE layer's cache (no leading layer dim).
+
+    ``block_size > 0`` gives the paged pool instead (attention families):
+    ``batch`` blocks of ``block_size`` tokens, head-major
+    ``(n_blocks, h_kv, block_size, hd)`` (see :func:`paged_kv_scatter`).
+    """
+    if block_size > 0:
+        pool = jax.ShapeDtypeStruct(
+            (batch, cfg.n_kv_heads, block_size, cfg.head_dim), cache_dtype)
+        return {"k": pool, "v": pool}
     if cfg.family == "ssm":
         s = cfg.ssm
         di = s.d_inner(cfg.d_model)

@@ -63,8 +63,10 @@ class AdamW:
     schedule: Callable = dataclasses.field(default=constant_schedule)
 
     def init(self, params):
+        # zeros_like keeps each leaf's sharding: the moments land on the
+        # devices that hold the params, not all on the default device
         zeros = jax.tree.map(
-            lambda p: jnp.zeros(p.shape, jnp.float32), params)
+            lambda p: jnp.zeros_like(p, jnp.float32), params)
         return {"m": zeros,
                 "v": jax.tree.map(jnp.zeros_like, zeros),
                 "count": jnp.zeros((), jnp.int32)}

@@ -461,7 +461,8 @@ class ServeEngine:
         if prefix_cache:
             self.prefix_cache = PrefixCache(self.store)
             self.prefix_cache.trace = self.trace
-        self.cache = pl.serve_cache_struct(cfg, self.eng, dry_run=False)
+        self.cache = pl.serve_cache_struct(cfg, self.eng, dry_run=False,
+                                            mesh=mesh)
         self.batcher = Batcher(self.eng.n_microbatches, self.mb_global,
                                self.n_chunks, self.eng.max_seq,
                                n_trials=self.n_arches,
@@ -1239,7 +1240,7 @@ def static_serve(cfg: ArchConfig, eng: pl.EngineConfig, mesh, params,
         tokens = np.zeros((1, eng.n_microbatches, mb_global, plen), np.int32)
         for i, r in enumerate(group):
             tokens[0, i // mb_global, i % mb_global] = r.prompt
-        cache = pl.serve_cache_struct(cfg, eng, dry_run=False)
+        cache = pl.serve_cache_struct(cfg, eng, dry_run=False, mesh=mesh)
         stats.ticks += 1
         admitted_tick = stats.ticks  # the group's prefill tick
         stats.calls += 1

@@ -107,8 +107,9 @@ class TransferEngine:
         """Extract the K/V payloads of pool blocks ``ids`` (device → host),
         eagerly — the caller frees the device blocks right after, so the
         bytes must be off the pool before this returns. Read-only: shared
-        blocks (refcount > 1) may be extracted safely. Returns one opaque
-        payload per id (``None`` each in bookkeeping mode)."""
+        blocks (refcount > 1) may be extracted safely. Returns one payload
+        per id: a host ``(2, Lp, h_kv, block_size, hd)`` array, K and V in
+        the pool's head-major layout (``None`` each in bookkeeping mode)."""
         ids = list(ids)
         self.swap_out_blocks += len(ids)
         if self.kernels is None or not ids:

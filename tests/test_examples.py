@@ -37,7 +37,10 @@ def test_example_runs(script, tmp_path):
            # one host device: the examples degrade to their single-device
            # paths (smallest compiles); the forced-8 flag from conftest.py
            # must not leak into the subprocess
-           "XLA_FLAGS": "--xla_force_host_platform_device_count=1"}
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=1",
+           # serve_decode.py runs the serve launcher, which turns on JAX's
+           # persistent compile cache; tests don't
+           "JAX_ENABLE_COMPILATION_CACHE": "false"}
     args = list(TINY_ARGS.get(script, []))
     if script == "model_selection.py":
         args += ["--ckpt-dir", str(tmp_path / "ckpt")]  # hermetic

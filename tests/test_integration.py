@@ -12,7 +12,9 @@ import sys
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ENV = {**os.environ,
        "PYTHONPATH": os.path.join(ROOT, "src"),
-       "XLA_FLAGS": "--xla_force_host_platform_device_count=8"}
+       "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
+       # the launchers turn on JAX's persistent compile cache; tests don't
+       "JAX_ENABLE_COMPILATION_CACHE": "false"}
 
 
 def _run(args, timeout=540):

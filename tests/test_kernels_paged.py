@@ -28,8 +28,8 @@ def make_paged_case(b, sq, hq, hkv, hd, nb, bs, n_tbl, kv_lens, dt):
     live tokens (the new sq arrive at the end); live blocks are a random
     disjoint subset of the pool, remaining table entries are -1."""
     q = jnp.asarray(RNG.normal(size=(b, sq, hq, hd)), dt)
-    k_pool = jnp.asarray(RNG.normal(size=(nb, bs, hkv, hd)), dt)
-    v_pool = jnp.asarray(RNG.normal(size=(nb, bs, hkv, hd)), dt)
+    k_pool = jnp.asarray(RNG.normal(size=(nb, hkv, bs, hd)), dt)
+    v_pool = jnp.asarray(RNG.normal(size=(nb, hkv, bs, hd)), dt)
     tables = np.full((b, n_tbl), -1, np.int32)
     free = list(RNG.permutation(nb))
     for r, ln in enumerate(kv_lens):
@@ -113,8 +113,10 @@ def test_kernel_vs_gathered_dense():
     q, k_pool, v_pool, tables, kv_offset, kv_len = case
     span = (jnp.clip(tables, 0, nb - 1)[:, :, None] * bs
             + jnp.arange(bs)[None, None, :]).reshape(b, n_tbl * bs)
-    k_rows = jnp.take(k_pool.reshape(nb * bs, hkv, hd), span, axis=0)
-    v_rows = jnp.take(v_pool.reshape(nb * bs, hkv, hd), span, axis=0)
+    k_rows = jnp.take(k_pool.transpose(0, 2, 1, 3).reshape(nb * bs, hkv, hd),
+                      span, axis=0)
+    v_rows = jnp.take(v_pool.transpose(0, 2, 1, 3).reshape(nb * bs, hkv, hd),
+                      span, axis=0)
     want = attention(q, k_rows, v_rows, causal=False, window=0,
                      kv_offset=0, kv_len=kv_len, opts=ModelOptions())
     for variant in ("loop", "blockspec"):
@@ -132,8 +134,8 @@ def test_scatter_overflow_leaves_last_block_untouched():
     cached K/V."""
     nb, bs, hkv, hd, n_tbl = 4, 4, 2, 8, 2  # capacity 2 blocks = 8 tokens
     cache = {
-        "k": jnp.asarray(RNG.normal(size=(nb, bs, hkv, hd)), jnp.float32),
-        "v": jnp.asarray(RNG.normal(size=(nb, bs, hkv, hd)), jnp.float32),
+        "k": jnp.asarray(RNG.normal(size=(nb, hkv, bs, hd)), jnp.float32),
+        "v": jnp.asarray(RNG.normal(size=(nb, hkv, bs, hd)), jnp.float32),
     }
     tables = jnp.asarray([[2, 1]], jnp.int32)  # full table, last block = 1
     k = jnp.asarray(RNG.normal(size=(1, 1, hkv, hd)), jnp.float32)
@@ -149,7 +151,7 @@ def test_scatter_overflow_leaves_last_block_untouched():
     # in-capacity writes still land: pos 5 -> block 1 slot 1
     new = blocks.paged_kv_scatter(cache, k, v, tables,
                                   jnp.asarray([5], jnp.int32))
-    np.testing.assert_array_equal(np.asarray(new["k"][1, 1]),
+    np.testing.assert_array_equal(np.asarray(new["k"][1, :, 1]),
                                   np.asarray(k[0, 0]))
     assert not np.array_equal(np.asarray(new["k"]), np.asarray(cache["k"]))
 
@@ -165,9 +167,9 @@ def _attn_case(cfg, mode, s, kv_lens, window=0):
     b = len(kv_lens)
     x = jnp.asarray(RNG.normal(size=(b, s, d)), jnp.float32)
     cache = {
-        "k": jnp.asarray(RNG.normal(size=(nb, bs, cfg.n_kv_heads,
+        "k": jnp.asarray(RNG.normal(size=(nb, cfg.n_kv_heads, bs,
                                           cfg.head_dim)), jnp.float32),
-        "v": jnp.asarray(RNG.normal(size=(nb, bs, cfg.n_kv_heads,
+        "v": jnp.asarray(RNG.normal(size=(nb, cfg.n_kv_heads, bs,
                                           cfg.head_dim)), jnp.float32),
     }
     tables = np.full((b, n_tbl), -1, np.int32)
@@ -288,8 +290,8 @@ def test_engine_kernel_requires_paged():
 
 def test_paged_mode_default_and_env(monkeypatch):
     monkeypatch.delenv("REPRO_PAGED_ATTN", raising=False)
-    assert ops._paged_mode() == ("jnp" if jax.default_backend() == "cpu"
-                                 else "pallas")
+    assert ops._paged_mode() == ("pallas" if jax.default_backend() == "tpu"
+                                 else "jnp")
     for m in ("pallas", "interpret", "jnp"):
         monkeypatch.setenv("REPRO_PAGED_ATTN", m)
         assert ops._paged_mode() == m

@@ -210,10 +210,10 @@ def test_hlo_analyzer_counts_loops_and_collectives():
     import jax
     from jax import lax
     from jax.sharding import PartitionSpec as P
-    from repro import compat
+    from repro.launch.mesh import make_mesh
     if jax.device_count() < 2:
         pytest.skip("needs >=2 devices")
-    mesh = compat.make_mesh((2,), ("x",))
+    mesh = make_mesh((2,), ("x",))
 
     def inner(w, x):
         def body(c, _):
@@ -223,8 +223,8 @@ def test_hlo_analyzer_counts_loops_and_collectives():
         out, _ = lax.scan(body, x, None, length=5)
         return out
 
-    f = compat.shard_map(inner, mesh=mesh, in_specs=(P(), P()), out_specs=P(),
-                         check_vma=False)
+    f = jax.shard_map(inner, mesh=mesh, in_specs=(P(), P()), out_specs=P(),
+                      check_vma=False)
     lowered = jax.jit(f).lower(
         jax.ShapeDtypeStruct((16, 16), jnp.float32),
         jax.ShapeDtypeStruct((4, 16), jnp.float32))
