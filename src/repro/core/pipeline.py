@@ -163,7 +163,7 @@ def trial_params_struct(cfg: ArchConfig, eng: EngineConfig, plan: StagePlan,
 @functools.lru_cache(maxsize=32)
 def _trial_params_init(cfg: ArchConfig, eng: EngineConfig, plan: StagePlan,
                        dtype, max_pos: int, mesh) -> Callable:
-    def init(key):
+    def init_params(key):
         keys = jax.random.split(key, eng.n_trials)
         params = jax.vmap(
             lambda k: lm.init_params(cfg, k, dtype=dtype, max_pos=max_pos,
@@ -183,7 +183,7 @@ def _trial_params_init(cfg: ArchConfig, eng: EngineConfig, plan: StagePlan,
         shardings = jax.tree.map(lambda s: NamedSharding(mesh, s),
                                  param_pspecs(cfg, eng),
                                  is_leaf=lambda x: isinstance(x, P))
-    return jax.jit(init, out_shardings=shardings)
+    return jax.jit(init_params, out_shardings=shardings)
 
 
 def init_trial_params(cfg: ArchConfig, eng: EngineConfig, plan: StagePlan,
@@ -548,12 +548,19 @@ def make_train_step(cfg: ArchConfig, opts: ModelOptions, eng: EngineConfig,
     Returns fn(params, opt_state, batch, hparams, step) ->
     (params, opt_state, metrics). ``hparams`` is a dict of (K,) arrays
     (per-trial learning rates etc. — Hydra's model-selection axis).
+
+    The program is ``jit_train_step``; its operations carry the named
+    scopes ``forward`` (the loss; the backward pass is its transpose,
+    ``transpose(jvp(forward))``, remat's recompute included),
+    ``grad_reduce`` and ``optimizer``, so a device trace splits the step.
+    Scopes are metadata: the compiled instructions are the same without
+    them (``tests/test_obs.py`` checks this).
     """
     pspecs = param_pspecs(cfg, eng)
     ospecs = optimizer.state_pspecs(pspecs)
     bspecs = batch_pspecs(cfg, eng, train=True)
 
-    def inner(params, opt_state, batch, hparams, step):
+    def train_step(params, opt_state, batch, hparams, step):
         # objective normalization: grads are psum'd over the data(+pod) axes,
         # so divide the local objective by the DP degree — the CE term then
         # equals the global-batch mean exactly; the MoE aux term is defined
@@ -561,16 +568,21 @@ def make_train_step(cfg: ArchConfig, opts: ModelOptions, eng: EngineConfig,
         dp_degree = eng.data_size * eng.pod_size
 
         def local_loss(p):
-            loss_vec, aux_vec = pipeline_train_loss(cfg, opts, eng, p, batch)
-            total = loss_vec.sum()
-            if cfg.moe is not None:
-                total = total + cfg.moe.load_balance_coef * aux_vec.sum()
-            return total / dp_degree, loss_vec
+            with jax.named_scope("forward"):
+                loss_vec, aux_vec = pipeline_train_loss(cfg, opts, eng, p,
+                                                        batch)
+                total = loss_vec.sum()
+                if cfg.moe is not None:
+                    total = total + cfg.moe.load_balance_coef * aux_vec.sum()
+                return total / dp_degree, loss_vec
 
         grads, loss_vec = jax.grad(local_loss, has_aux=True)(params)
-        grads, gnorm = reduce_grads(cfg, eng, grads)
-        params_new, opt_new = optimizer.update(params, grads, opt_state,
-                                               hparams, step, grad_norm=gnorm)
+        with jax.named_scope("grad_reduce"):
+            grads, gnorm = reduce_grads(cfg, eng, grads)
+        with jax.named_scope("optimizer"):
+            params_new, opt_new = optimizer.update(params, grads, opt_state,
+                                                   hparams, step,
+                                                   grad_norm=gnorm)
         # per-trial loss averaged over the data(+pod) axes
         for ax in eng.dp_axes:
             loss_vec = lax.pmean(loss_vec, ax)
@@ -578,7 +590,7 @@ def make_train_step(cfg: ArchConfig, opts: ModelOptions, eng: EngineConfig,
         return params_new, opt_new, metrics
 
     mapped = jax.shard_map(
-        inner, mesh=mesh,
+        train_step, mesh=mesh,
         in_specs=(pspecs, ospecs, bspecs, P(), P()),
         out_specs=(pspecs, ospecs, {"loss": P(), "grad_norm": P()}),
         check_vma=False)
@@ -1042,10 +1054,12 @@ def make_serve_step(cfg: ArchConfig, opts: ModelOptions, eng: EngineConfig,
     along during admission and vice versa).
     ``tracer`` (an *enabled* ``repro.obs.Tracer``) wraps the step to emit a
     ``compile`` event on the first call of each (token qlen, block-table
-    width) shape signature — exactly the signatures XLA retraces, so the
-    serving timeline shows every shape-bucket recompile. Pass None (not a
-    NullTracer) when tracing is off: the returned step is then the bare
-    jitted fn with zero wrapper overhead.
+    width) shape signature in this engine — the signatures JAX traces
+    anew. It does not mark an XLA compile: JAX's caches may already hold
+    the program (the tracer's ``xla_compile`` events are the real
+    compiles). Pass None (not a NullTracer) when tracing is off: the
+    returned step is then the bare jitted fn with zero wrapper overhead.
+    The program is ``jit_serve_<mode>``.
     Returns fn(params, cache, batch) -> (new_cache, tokens, logit_max).
     """
     if mode in ("append", "mixed", "verify") and cfg.rope == "mrope":
@@ -1081,11 +1095,12 @@ def make_serve_step(cfg: ArchConfig, opts: ModelOptions, eng: EngineConfig,
     else:
         batch_ax = P() if eng.batch_replicated else P(None, None, eng.dp_axes)
 
-    def inner(params, cache, batch):
+    def serve(params, cache, batch):
         return pipeline_serve(cfg, opts, eng, params, cache, batch, mode)
 
+    serve.__name__ = serve.__qualname__ = f"serve_{mode}"
     mapped = jax.shard_map(
-        inner, mesh=mesh,
+        serve, mesh=mesh,
         in_specs=(pspecs, cspecs, bspecs),
         out_specs=(cspecs, batch_ax, batch_ax),
         check_vma=False)
@@ -1124,7 +1139,7 @@ def make_slot_reset(cfg: ArchConfig, eng: EngineConfig, mesh,
     cspecs = serve_cache_pspecs(cfg, eng)
     mspec = P(None, None, None if eng.batch_replicated else eng.dp_axes)
 
-    def inner(cache, mask):
+    def slot_reset(cache, mask):
         def zero(buf):
             mk = mask.reshape(mask.shape[:2] + (1, mask.shape[2])
                               + (1,) * (buf.ndim - 4))
@@ -1134,7 +1149,7 @@ def make_slot_reset(cfg: ArchConfig, eng: EngineConfig, mesh,
                 "shared": (jax.tree.map(zero, cache["shared"])
                            if cache["shared"] is not None else None)}
 
-    mapped = jax.shard_map(inner, mesh=mesh, in_specs=(cspecs, mspec),
+    mapped = jax.shard_map(slot_reset, mesh=mesh, in_specs=(cspecs, mspec),
                            out_specs=cspecs, check_vma=False)
     if not jit:
         return mapped
@@ -1184,7 +1199,7 @@ def make_transfer_kernels(cfg: ArchConfig, eng: EngineConfig, mesh,
     cspecs = serve_cache_pspecs(cfg, eng)
     ispec = P(None, None if eng.batch_replicated else eng.dp_axes, None)
 
-    def inner(cache, src, dst):
+    def transfer_copy(cache, src, dst):
         s, d = src[:, 0], dst[:, 0]  # local shard: (K, n_copies)
 
         def upd(buf):  # (K, Lp_local, nb_local, h_kv, bs, hd)
@@ -1199,7 +1214,7 @@ def make_transfer_kernels(cfg: ArchConfig, eng: EngineConfig, mesh,
 
         return {"layers": jax.tree.map(upd, cache["layers"]), "shared": None}
 
-    mapped = jax.shard_map(inner, mesh=mesh,
+    mapped = jax.shard_map(transfer_copy, mesh=mesh,
                            in_specs=(cspecs, ispec, ispec),
                            out_specs=cspecs, check_vma=False)
     copy_fn = jax.jit(mapped, donate_argnums=(0,)) if jit else mapped

@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.core.pipeline import EngineConfig
+from repro.obs.tracer import resolve
 
 
 def _philox(seed: int, *counters: int) -> np.random.Generator:
@@ -71,13 +72,17 @@ def _gen_tokens(vocab: int, seq: int, eng: EngineConfig, step: int,
 
 
 class TrainBatches:
-    """Iterator of slot-major train batches with background prefetch."""
+    """Iterator of slot-major train batches with background prefetch.
+
+    ``tracer`` (``repro.obs.Tracer``) records a ``data.batch`` span around
+    each ``batch_for_step`` call; the prefetch thread records none."""
 
     def __init__(self, cfg: ArchConfig, eng: EngineConfig, seq_len: int,
                  seed: int = 0, prefetch: int = 2,
-                 frontend_fn=None, mrope_fn=None):
+                 frontend_fn=None, mrope_fn=None, tracer=None):
         self.cfg, self.eng, self.seq_len, self.seed = cfg, eng, seq_len, seed
         self.frontend_fn, self.mrope_fn = frontend_fn, mrope_fn
+        self.trace = resolve(tracer)
         self._q: queue.Queue = queue.Queue(maxsize=prefetch)
         self._stop = threading.Event()
         self._step = 0
@@ -85,6 +90,10 @@ class TrainBatches:
         self._thread.start()
 
     def batch_for_step(self, step: int) -> dict:
+        with self.trace.span("data.batch"):
+            return self._batch(step)
+
+    def _batch(self, step: int) -> dict:
         full = _gen_tokens(self.cfg.vocab_size, self.seq_len, self.eng, step,
                            self.seed)
         batch = {"tokens": full[..., :-1], "labels": full[..., 1:]}
@@ -105,7 +114,7 @@ class TrainBatches:
 
     def _producer(self):
         while not self._stop.is_set():
-            b = self.batch_for_step(self._step)
+            b = self._batch(self._step)
             self._step += 1
             while not self._stop.is_set():
                 try:

@@ -19,9 +19,11 @@ about utilization — as tracks you can see idle gaps on:
 * **queues** (pid 3) — one per-arch queue-depth counter track, with
   ``enqueue`` instants.
 * **compile** (pid 4) — one instant per first-seen pipeline-program shape
-  signature (mode × token width × table bucket).
-* **search** (pid 5) — ``span_begin``/``span_end`` pairs (hydra gangs and
-  successive-halving rungs) as wall-clock duration slices.
+  signature (mode × token width × table bucket), and one slice per
+  ``xla_compile`` (a real XLA compile or cache load), ending at its stamp.
+* **search** (pid 5) — ``span_begin``/``span_end`` pairs (hydra rungs,
+  gangs, set-up, data and dispatch spans) as wall-clock duration slices,
+  one track per nesting depth.
 
 Engine events are timestamped in *ticks* (1 tick rendered as
 ``TICK_US`` µs — the deterministic scheduling unit); search spans are
@@ -113,7 +115,7 @@ def to_chrome_trace(events) -> dict:
     out = []
     cell_tids: dict = {}  # (k, m, b) -> tid
     open_res: dict = {}  # rid -> (cell, start_tick, kind)
-    span_stack: dict = {}  # name -> [start events]
+    open_spans: dict = {}  # span id -> its span_begin event
     last_tick = 0
     out.append(_meta(_PID_CELLS, "serve cells"))
     out.append(_meta(_PID_POOL, "pool"))
@@ -194,19 +196,27 @@ def to_chrome_trace(events) -> dict:
             out.append(_instant(_PID_COMPILE, 0,
                                 int(ev.get("wall", 0.0) * 1e6),
                                 f"compile {ev.get('mode', '?')}", _args(ev)))
+        elif name == "xla_compile":
+            dur = max(int(ev.get("seconds", 0.0) * 1e6), 1)
+            out.append(_slice(_PID_COMPILE, 1,
+                              int(ev.get("wall", 0.0) * 1e6) - dur, dur,
+                              f"xla {ev.get('program', '?')}", _args(ev)))
         elif name == "span_begin":
-            span_stack.setdefault(ev.get("name", "span"), []).append(ev)
+            open_spans[ev.get("id")] = ev
         elif name == "span_end":
-            stack = span_stack.get(ev.get("name", "span"))
-            if stack:
-                start = stack.pop()
+            start = open_spans.pop(ev.get("id"), None)
+            if start is not None:
+                depth, parent = 0, start.get("parent")
+                while parent in open_spans:
+                    depth += 1
+                    parent = open_spans[parent].get("parent")
                 ts0 = int(start.get("wall", 0.0) * 1e6)
                 dur = max(int(ev.get("wall", 0.0) * 1e6) - ts0, 1)
                 label = start.get("name", "span")
                 detail = start.get("label") or start.get("arch")
                 if detail is not None:
                     label = f"{label} {detail}"
-                out.append(_slice(_PID_SEARCH, len(stack), ts0, dur, label,
+                out.append(_slice(_PID_SEARCH, depth, ts0, dur, label,
                                   _args(start, drop=("ev", "tick", "wall"))))
     for rid in sorted(open_res):  # truncated trace: close at last tick
         close_residency(rid, last_tick + 1, "open")

@@ -9,7 +9,11 @@ Two implementations share one interface:
   engine holds when tracing is off. Every method is a no-op and
   ``enabled`` is False, so hot emission sites guard with
   ``if tracer.enabled:`` and pay one attribute read + branch per *site*
-  (not per event) — no kwargs dict is ever built on the disabled path.
+  (not per event) — a guarded site builds no kwargs dict on the disabled
+  path. ``NullTracer.span`` hands back one shared no-op context: the
+  per-step sites (``data.batch``, ``step.dispatch``) pass no fields, so
+  each costs an attribute read, a call and the ``with``; the once-a-gang
+  sites (``gang``, ``rung``) also build their fields' dict.
 
 Event taxonomy (the ``ev`` field):
 
@@ -26,20 +30,76 @@ peak, per-arch queue depths, slot occupancy.
 
 Subsystem instants: ``prefix_spill`` / ``prefix_evict`` /
 ``host_evict`` (tiered store + radix cache), ``compile`` (first sight of
-a (mode, token shape, table bucket) pipeline-program signature).
+a (mode, token shape, table bucket) pipeline-program signature in one
+engine).
 
-Search spans: ``span_begin`` / ``span_end`` (``name`` = gang | rung)
-with wall timestamps — the successive-halving timeline of ``core.hydra``.
+Spans (:meth:`Tracer.span`): ``span_begin`` / ``span_end`` pairs with a
+per-tracer ``id`` and the enclosing span's id as ``parent``. The training
+path opens ``rung`` and ``gang`` (``core.hydra``), ``build.params`` /
+``build.optimizer`` / ``build.step`` (the gang's set-up), ``data.batch``
+(``TrainBatches.batch_for_step``) and ``step.dispatch`` (the call into
+the jitted train step). Each span is also a
+``jax.profiler.TraceAnnotation`` of the same name, so while a profiler
+runs it lands in the profiler's trace on the trace's own clock.
+
+``xla_compile``: one per XLA backend compile (or load from JAX's
+persistent cache) that happens while a span of this tracer is open on
+the compiling thread, from ``jax.monitoring``: ``program`` (JAX's name
+for it, e.g. ``jit(train_step)``), ``seconds``, ``cache_hit`` (the
+executable came from the persistent cache) and ``span`` (the name of the
+innermost open span).
 
 Timestamps: ``tick`` is the engine round (set once per round via
 :meth:`begin_tick`; emission sites never thread it), ``wall`` is seconds
-since the tracer was constructed. Search spans are wall-only
-(``tick`` = -1 outside an engine round).
+since the tracer was constructed, from ``time.perf_counter_ns``. Spans
+are wall-only (``tick`` = -1 outside an engine round). :meth:`anchor`
+ties ``wall`` to a running profiler's clock.
 """
 from __future__ import annotations
 
+import contextlib
+import threading
 import time
 from typing import Optional
+
+import jax
+
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_CACHE_READ = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+_thread = threading.local()
+_listening = False
+
+
+def _active() -> list:
+    """The tracers with a span open on this thread, innermost last: the
+    compile listener reports to the last one."""
+    stack = getattr(_thread, "tracers", None)
+    if stack is None:
+        stack = _thread.tracers = []
+    return stack
+
+
+def _on_duration(event: str, seconds: float, **kw) -> None:
+    stack = _active()
+    if not stack:
+        return
+    tr = stack[-1]
+    if event == _CACHE_READ:
+        tr._cache_read = True
+    elif event == _BACKEND_COMPILE:
+        tr.emit("xla_compile", program=kw.get("fun_name"), seconds=seconds,
+                cache_hit=tr._cache_read, span=tr._spans[-1][1])
+        tr._cache_read = False
+
+
+def _listen() -> None:
+    """Register the one ``jax.monitoring`` listener (listeners cannot be
+    removed, so it is registered once per process)."""
+    global _listening
+    if not _listening:
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        _listening = True
 
 
 class Tracer:
@@ -51,7 +111,11 @@ class Tracer:
     def __init__(self):
         self.events: list = []
         self.tick = -1  # current engine round; -1 = outside any round
-        self._t0 = time.monotonic()
+        self._t0 = time.perf_counter_ns()
+        self._spans: list = []  # (id, name) of the open spans, innermost last
+        self._next_id = 0
+        self._cache_read = False
+        _listen()
 
     # -- timestamps ----------------------------------------------------------
 
@@ -61,7 +125,17 @@ class Tracer:
         self.tick = tick
 
     def _wall(self) -> float:
-        return time.monotonic() - self._t0
+        return (time.perf_counter_ns() - self._t0) / 1e9
+
+    def anchor(self) -> None:
+        """Write an ``obs.clock`` annotation that carries this tracer's
+        ``wall`` at its start into a running profiler trace. An event
+        stamped ``wall`` then sits at ``start + (wall - anchor_wall)`` on
+        the trace's clock, in seconds: set-up spans and compiles recorded
+        before the profiler started line up with the device's time. Call
+        it right after ``jax.profiler.start_trace``."""
+        with jax.profiler.TraceAnnotation("obs.clock", wall=self._wall()):
+            pass
 
     # -- emission ------------------------------------------------------------
 
@@ -80,25 +154,43 @@ class Tracer:
         self.emit("round", **fields)
 
     def compile(self, mode: str, **fields) -> None:
-        """First sight of a pipeline-program shape signature — each one is
-        an XLA compile the serving timeline should show."""
+        """First sight of a pipeline-program shape signature in one serve
+        engine. It is not an XLA compile: JAX's caches may already hold
+        that program. Real compiles are the ``xla_compile`` events."""
         self.emit("compile", mode=mode, **fields)
 
-    def span_begin(self, name: str, **fields) -> None:
-        self.emit("span_begin", name=name, **fields)
-
-    def span_end(self, name: str, **fields) -> None:
-        self.emit("span_end", name=name, **fields)
+    @contextlib.contextmanager
+    def span(self, name: str, **fields):
+        """Record ``name`` as a ``span_begin``/``span_end`` pair around the
+        block (with ``fields`` on the begin event), nested under the
+        innermost open span, and as a profiler annotation of that name."""
+        sid = self._next_id
+        self._next_id += 1
+        parent = self._spans[-1][0] if self._spans else None
+        self.emit("span_begin", name=name, id=sid, parent=parent, **fields)
+        self._spans.append((sid, name))
+        active = _active()
+        active.append(self)
+        try:
+            with jax.profiler.TraceAnnotation(name):
+                yield sid
+        finally:
+            active.pop()
+            self._spans.pop()
+            self.emit("span_end", name=name, id=sid)
 
     # -- management ----------------------------------------------------------
 
     def clear(self) -> None:
         self.events = []
         self.tick = -1
-        self._t0 = time.monotonic()
+        self._t0 = time.perf_counter_ns()
 
     def __len__(self) -> int:
         return len(self.events)
+
+
+_NULL_SPAN = contextlib.nullcontext()
 
 
 class NullTracer:
@@ -113,6 +205,9 @@ class NullTracer:
     def begin_tick(self, tick: int) -> None:
         pass
 
+    def anchor(self) -> None:
+        pass
+
     def emit(self, ev: str, **fields) -> None:
         pass
 
@@ -125,11 +220,8 @@ class NullTracer:
     def compile(self, mode: str, **fields) -> None:
         pass
 
-    def span_begin(self, name: str, **fields) -> None:
-        pass
-
-    def span_end(self, name: str, **fields) -> None:
-        pass
+    def span(self, name: str, **fields):
+        return _NULL_SPAN
 
     def clear(self) -> None:
         pass

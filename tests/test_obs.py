@@ -1,20 +1,33 @@
 """Observability layer: bounded reservoirs, the metric registry behind
-``ServeStats``, tracer on/off semantics, JSONL + Perfetto export
-round-trips, the span validator, and a traced-vs-untraced engine parity
-check (tracing must never change the schedule or the tokens).
+``ServeStats``, tracer on/off semantics, spans and their place on the
+profiler's clock, XLA compile events, JSONL + Perfetto export
+round-trips, the span validator, a traced-vs-untraced engine parity
+check (tracing must never change the schedule or the tokens), and the
+same for the gang's train path (scopes and spans change no instruction
+and no loss).
 
 (Multi-device setup comes from tests/conftest.py — pytest-only module.)"""
+import contextlib  # noqa: E402
 import dataclasses  # noqa: E402
+import glob  # noqa: E402
 import json  # noqa: E402
+import re  # noqa: E402
+import time  # noqa: E402
+import tracemalloc  # noqa: E402
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
-from repro.configs import ASSIGNED_ARCHS  # noqa: E402
+from repro.configs import ASSIGNED_ARCHS, get_config  # noqa: E402
 from repro.core import pipeline as pl  # noqa: E402
+from repro.core.hydra import (HydraConfig, HydraRunner,  # noqa: E402
+                              run_model_selection)
 from repro.core.partitioner import plan_stages  # noqa: E402
+from repro.core.scheduler import GangPlan  # noqa: E402
+from repro.core.trials import grid_search  # noqa: E402
+from repro.data.pipeline import TrainBatches  # noqa: E402
 from repro.launch.mesh import make_test_mesh  # noqa: E402
 from repro.models.layers import ModelOptions  # noqa: E402
 from repro.obs import (NULL_TRACER, TraceInvariantError,  # noqa: E402
@@ -23,6 +36,7 @@ from repro.obs import (NULL_TRACER, TraceInvariantError,  # noqa: E402
                        write_perfetto)
 from repro.obs.metrics import (DEFAULT_RESERVOIR_CAP, MetricRegistry,
                                Reservoir)  # noqa: E402
+from repro.optim.adamw import AdamW  # noqa: E402
 from repro.serve import Request, ServeEngine  # noqa: E402
 from repro.serve.engine import ServeStats  # noqa: E402
 
@@ -110,8 +124,8 @@ def test_disabled_tracer_emits_nothing():
         tr.emit("x", a=1)
         tr.req("admit", 0, k=0)
         tr.round(modes=["decode"])
-        tr.span_begin("gang")
-        tr.span_end("gang")
+        with tr.span("gang"):
+            tr.anchor()
         assert len(tr.events) == 0 and len(tr) == 0
 
 
@@ -127,6 +141,230 @@ def test_tracer_stamps_tick_and_wall():
     assert rnd["ev"] == "round" and rnd["modes"] == ["decode"]
     tr.clear()
     assert len(tr) == 0
+
+
+def test_span_nesting_and_parent_ids():
+    tr = Tracer()
+    with tr.span("gang", arch="a") as gang:
+        with tr.span("data.batch"):
+            pass
+        with tr.span("step.dispatch") as dispatch:
+            with tr.span("inner"):
+                pass
+    with pytest.raises(ValueError):
+        with tr.span("failed"):
+            raise ValueError("the span still closes")
+    begins = [e for e in tr.events if e["ev"] == "span_begin"]
+    ends = [e for e in tr.events if e["ev"] == "span_end"]
+    assert [(e["name"], e["parent"]) for e in begins] == [
+        ("gang", None), ("data.batch", gang), ("step.dispatch", gang),
+        ("inner", dispatch), ("failed", None)]
+    assert len({e["id"] for e in begins}) == 5
+    assert begins[0]["arch"] == "a" and "arch" not in begins[1]
+    assert [e["name"] for e in ends] == ["data.batch", "inner",
+                                         "step.dispatch", "gang", "failed"]
+    end_of = {e["id"]: e["wall"] for e in ends}
+    assert all(e["wall"] <= end_of[e["id"]] for e in begins)
+    # Perfetto: one track per nesting depth
+    recs = to_chrome_trace(tr.events)["traceEvents"]
+    tid = {r["name"]: r["tid"] for r in recs if r["ph"] == "X"}
+    assert (tid["gang a"], tid["data.batch"], tid["inner"]) == (0, 1, 2)
+
+
+def test_null_tracer_span_allocates_nothing():
+    span = NULL_TRACER.span
+    assert span("data.batch") is span("step.dispatch")
+
+    def calls(n):
+        for _ in range(n):
+            with span("data.batch"):
+                pass
+
+    calls(100)
+    tracemalloc.start()
+    try:
+        before = tracemalloc.get_traced_memory()[0]
+        calls(10_000)
+        after, peak = tracemalloc.get_traced_memory()
+    finally:
+        tracemalloc.stop()
+    # nothing survives a call, and nothing piles up across 10,000 of them
+    assert after <= before and peak - before < 1024
+
+
+def test_clock_anchor_places_a_span_on_the_profiler_clock(tmp_path):
+    from jax.profiler import ProfileData
+    tr = Tracer()
+    with tr.span("obs.test.before"):  # stamped before the profiler ran
+        time.sleep(0.002)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        tr.anchor()
+        time.sleep(0.05)
+        with tr.span("obs.test.span"):
+            time.sleep(0.01)
+    finally:
+        jax.profiler.stop_trace()
+    [path] = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    found = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("obs."):
+                    found[e.name] = e
+    assert "obs.test.before" not in found
+    clock = found["obs.clock"]
+    begin = next(e for e in tr.events if e["ev"] == "span_begin"
+                 and e["name"] == "obs.test.span")
+    anchor_wall = next(v for k, v in clock.stats if k == "wall")
+    placed = clock.start_ns + (begin["wall"] - anchor_wall) * 1e9
+    assert abs(placed - found["obs.test.span"].start_ns) < 1e6
+
+
+# ------------------------------------------------------ the gang's train path
+
+
+def _tiny_gang(tracer=None, n_stages=1):
+    cfg = get_config("bert-large").reduced()
+    eng = pl.EngineConfig(n_trials=2, n_microbatches=2, microbatch=2,
+                          n_stages=n_stages, data_size=1)
+    trials = tuple(grid_search(cfg.name, [1e-3, 3e-4], (0.01,)))
+    runner = HydraRunner(cfg, ModelOptions(remat=True),
+                         make_test_mesh(1, n_stages),
+                         HydraConfig(seq_len=16, steps=2), tracer=tracer)
+    return runner, GangPlan(cfg.name, trials, eng)
+
+
+def _setup_losses(tracer, steps=3):
+    runner, gang = _tiny_gang(tracer)
+    p, o, hp, step_fn = runner._build(gang)
+    data = TrainBatches(runner.cfg, gang.engine, 16, seed=3, tracer=tracer)
+    losses = []
+    try:
+        for t in range(steps):
+            p, o, m = step_fn(p, o, data.batch_for_step(t), hp,
+                              jnp.asarray(t, jnp.int32))
+            losses.append(np.asarray(m["loss"]))
+    finally:
+        data.close()
+    return np.stack(losses)
+
+
+def test_gang_losses_identical_with_tracer_on_and_off():
+    off = _setup_losses(None)
+    tr = Tracer()
+    on = _setup_losses(tr)
+    assert np.array_equal(on, off)
+    begins = [e for e in tr.events if e["ev"] == "span_begin"]
+    assert [e["name"] for e in begins] == (
+        ["build.params", "build.optimizer", "build.step"]
+        + ["data.batch", "step.dispatch"] * 3)
+    assert all(e["parent"] is None for e in begins)
+    # the off run compiled everything else; this one makes its own step
+    compiles = [e for e in tr.events if e["ev"] == "xla_compile"]
+    assert {e["program"] for e in compiles} == {"jit(train_step)"}
+    assert all(e["span"] == "step.dispatch" and e["seconds"] > 0
+               and e["cache_hit"] is False for e in compiles)
+
+
+def test_model_selection_spans_nest_rung_gang_build():
+    runner, gang = _tiny_gang()
+    tr = Tracer()
+    out = run_model_selection(runner.cfg, runner.opts, runner.mesh,
+                              HydraConfig(seq_len=16, steps=1),
+                              gang.trials, gang.engine, tracer=tr)
+    assert len(out["all"]) == 2
+    begins = {e["id"]: e for e in tr.events if e["ev"] == "span_begin"}
+
+    def path(e):
+        return (path(begins[e["parent"]]) + "/" if e["parent"] is not None
+                else "") + e["name"]
+
+    paths = {path(e) for e in begins.values()}
+    assert {"rung", "rung/gang", "rung/gang/build.params",
+            "rung/gang/data.batch", "rung/gang/step.dispatch"} <= paths
+    programs = {e["program"] for e in tr.events if e["ev"] == "xla_compile"}
+    assert {"jit(train_step)", "jit(eval_loss)"} <= programs
+
+
+@contextlib.contextmanager
+def _persistent_cache(path):
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    keys = {"jax_compilation_cache_dir": str(path),
+            "jax_persistent_cache_min_compile_time_secs": 0.0,
+            "jax_persistent_cache_min_entry_size_bytes": 0}
+    old = {k: getattr(jax.config, k) for k in keys}
+    for k, v in keys.items():
+        jax.config.update(k, v)
+    cc.reset_cache()
+    try:
+        yield
+    finally:
+        for k, v in old.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+
+
+def test_xla_compile_names_train_step_and_marks_a_cache_hit(tmp_path):
+    runner, gang = _tiny_gang()
+    p, o, hp, step_fn = runner._build(gang)
+    data = TrainBatches(runner.cfg, gang.engine, 16, seed=3)
+    batch = data.batch_for_step(0)
+    data.close()
+    args = (hp, jnp.asarray(0, jnp.int32))
+    lowered = step_fn.lower(p, o, batch, *args)
+    tr = Tracer()
+    with _persistent_cache(tmp_path):
+        with tr.span("first"):
+            lowered.compile()
+        jax.clear_caches()  # the next compile has to find it on disk
+        with tr.span("again"):
+            step_fn.lower(p, o, batch, *args).compile()
+    [first, again] = [e for e in tr.events if e["ev"] == "xla_compile"]
+    assert first["program"] == again["program"] == "jit(train_step)"
+    assert (first["span"], first["cache_hit"]) == ("first", False)
+    assert (again["span"], again["cache_hit"]) == ("again", True)
+    # no span open: nothing is recorded
+    n = len(tr)
+    jax.jit(lambda x: x * 3 + 1)(jnp.ones(3))
+    assert len(tr) == n
+
+
+def _instructions(hlo: str) -> list:
+    """A compiled module's instructions without their metadata, leaving
+    out the module's name and its source-location tables."""
+    out = []
+    for line in hlo.splitlines():
+        line = line.strip()
+        if line.startswith(("%", "ROOT %", "ENTRY")):
+            out.append(re.sub(r", metadata=\{[^}]*\}", "", line))
+    return out
+
+
+def test_train_step_scopes_change_no_instruction(monkeypatch):
+    runner, gang = _tiny_gang(n_stages=2)
+    p, o, hp, _ = runner._build(gang)
+    eng = gang.engine
+    batch = {k: np.zeros((2, 2, 2, 16), np.int32) for k in ("tokens",
+                                                              "labels")}
+
+    def compiled():
+        fn = pl.make_train_step(runner.cfg, runner.opts, eng, runner.mesh,
+                                runner.optimizer)
+        return fn.lower(p, o, batch, hp,
+                        jnp.asarray(0, jnp.int32)).compile().as_text()
+
+    scoped = compiled()
+    names = re.findall(r'op_name="([^"]*)"', scoped)
+    for scope in ("/jvp(forward)/", "/transpose(jvp(forward))/",
+                  "/grad_reduce/", "/optimizer/", "/rematted_computation/"):
+        assert any(scope in n for n in names), scope
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = compiled()
+    assert "forward" not in bare
+    assert _instructions(scoped) == _instructions(bare)
+    assert len(_instructions(scoped)) > 1000
 
 
 # ----------------------------------------------------------------- export --
@@ -150,8 +388,9 @@ def _lifecycle_events():
     tr.begin_tick(5)
     tr.req("complete", 1, tokens=3, ttft=1.0)
     tr.compile("decode", qlen=1, table_width=0)
-    tr.span_begin("gang", arch="a", n_trials=2, steps=4)
-    tr.span_end("gang", arch="a")
+    with tr.span("gang", arch="a", n_trials=2, steps=4):
+        tr.emit("xla_compile", program="jit(train_step)", seconds=0.25,
+                cache_hit=False, span="gang")
     return tr.events
 
 
@@ -192,6 +431,8 @@ def test_perfetto_trace_structure(tmp_path):
     assert any(r["name"] == "compile decode" for r in recs)
     gang = [r for r in recs if r["ph"] == "X" and r["name"] == "gang a"]
     assert len(gang) == 1 and gang[0]["dur"] >= 1
+    [xla] = [r for r in recs if r["name"] == "xla jit(train_step)"]
+    assert xla["ph"] == "X" and xla["dur"] == 250_000
     path = str(tmp_path / "t.json")
     assert write_perfetto(_lifecycle_events(), path) == len(recs)
     assert json.load(open(path))["traceEvents"]
@@ -311,6 +552,29 @@ def _traced_pair():
                                     (8 + 4 * (i % 2),)).astype(np.int32),
                     3 + i % 3, arrival=0.7 * i) for i in range(6)]
     return cfg, eng, mesh, params, opts, reqs
+
+
+@pytest.mark.parametrize("build, name", [
+    (lambda cfg, eng, mesh: pl._trial_params_init(
+        cfg, eng, plan_stages(cfg, eng.n_stages), jnp.dtype(jnp.float32),
+        MAX_SEQ, mesh), "init_params"),
+    (lambda cfg, eng, mesh: pl.make_train_step(
+        cfg, ModelOptions(), dataclasses.replace(eng, paged=False), mesh,
+        AdamW()), "train_step"),
+    (lambda cfg, eng, mesh: pl.make_serve_step(
+        cfg, ModelOptions(), eng, mesh, "decode"), "serve_decode"),
+    (lambda cfg, eng, mesh: pl.make_serve_step(
+        cfg, ModelOptions(), eng, mesh, "mixed"), "serve_mixed"),
+    (lambda cfg, eng, mesh: pl.make_slot_reset(
+        cfg, dataclasses.replace(eng, paged=False), mesh), "slot_reset"),
+    (lambda cfg, eng, mesh: pl.make_transfer_kernels(cfg, eng, mesh).copy,
+     "transfer_copy"),
+])
+def test_jitted_programs_have_stable_names(build, name):
+    """``xla_compile`` events and the trace's module names come from
+    these: each program is ``jit(<name>)``, module ``jit_<name>``."""
+    cfg, eng, mesh, *_ = _traced_pair()
+    assert build(cfg, eng, mesh).__name__ == name
 
 
 def test_engine_trace_matches_untraced_run():
