@@ -65,7 +65,11 @@ class HydraRunner:
     def _build(self, gang: GangPlan):
         """The gang's parameters, AdamW state, per-trial hyperparameters and
         jitted train step. With the tracer on, the step is wrapped in a
-        ``step.dispatch`` span; with it off, it is the bare jitted step."""
+        ``step.dispatch`` span; with it off, it is the bare jitted step.
+        The ``build.step`` span carries the step's remat stash
+        (``pipeline.train_stash``): its scheme, ``"layers"`` or ``"ticks"``
+        (``None`` without remat), the layer inputs it keeps per tick and its
+        estimated bytes."""
         eng = gang.engine
         tr = self.trace
         with tr.span("build.params"):
@@ -82,7 +86,11 @@ class HydraRunner:
             "wd": jnp.asarray([t.weight_decay for t in gang.trials],
                               jnp.float32),
         }
-        with tr.span("build.step"):
+        kept, stash_bytes = pl.train_stash(
+            self.cfg, self.opts, eng, self.hc.seq_len, self.hc.param_dtype)
+        stash = ("layers" if kept else "ticks") if self.opts.remat else None
+        with tr.span("build.step", stash=stash, stash_layers=kept,
+                     stash_bytes=stash_bytes):
             step_fn = pl.make_train_step(self.cfg, self.opts, eng, self.mesh,
                                          self.optimizer)
         return params, opt_state, hparams, with_dispatch_span(step_fn, tr)

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig
@@ -84,8 +85,11 @@ class EngineConfig:
     prefill_chunks: int = 1  # >1: chunked prefill — sequence chunks become
     # extra pipeline slots (Hydra's slot-filling applied within one request);
     # chunk c attends to the cache written by chunks < c (mode="append")
-    layer_remat: bool = True  # inner per-layer checkpoint (False = tick-level
-    # remat only: one fewer weight-gather round in backward)
+    layer_remat: bool = True  # inner per-layer checkpoint inside the
+    # tick-level one; the tick also keeps the inputs of as many layers as
+    # fit (``train_stash``; on one stage all, as one tick is live at a
+    # time), which its backward then does not replay twice.
+    # False = tick-level remat only: one fewer weight-gather round in backward
 
     @property
     def n_slots(self) -> int:
@@ -94,6 +98,14 @@ class EngineConfig:
     @property
     def n_ticks(self) -> int:
         return self.n_slots + self.n_stages - 1
+
+    @property
+    def stash_ticks(self) -> int:
+        """Ticks whose activations the train step's backward holds at once:
+        one for a one-stage gang, whose step runs each tick's backward
+        right after its forward (``tickwise_train_grads``); every tick for
+        a pipeline, whose backward follows all its ticks' forwards."""
+        return 1 if self.n_stages == 1 else self.n_ticks
 
     @property
     def dp_axes(self):
@@ -397,13 +409,42 @@ def _take1(tree, i):
         lambda l: lax.dynamic_index_in_dim(l, i, 0, keepdims=False), tree)
 
 
+# checkpoint name of a tick's stage output (kept with the layer inputs)
+STAGE_OUTPUT = "stage_output"
+
+
+def train_stash(cfg: ArchConfig, opts: ModelOptions, eng: EngineConfig,
+                seq_len: int, param_dtype) -> tuple:
+    """The activations the train step's backward keeps per chip, as
+    (layers, bytes): the layer inputs each tick keeps
+    (``scheduler.stash_layers``, from the shapes, the dtypes and the chip's
+    HBM) and the stash's bytes (``scheduler.stash_bytes``). With
+    ``layers`` > 0 each tick keeps the inputs of its stage's last
+    ``layers`` layers and the stage output, so its backward replays only
+    the other layers before each layer replays its own forward inside its
+    checkpoint: a kept layer's forward runs twice in a step, not three
+    times. With 0, the tick stash: each tick keeps its stage input only.
+    Without ``opts.remat`` no tick is rematerialized: ``(0, 0)``.
+    """
+    if not opts.remat:
+        return 0, 0
+    # imported here: the scheduler imports this module
+    from repro.core.scheduler import stash_bytes, stash_layers
+    act = jnp.dtype(opts.compute_dtype).itemsize
+    kept = stash_layers(cfg, eng, seq_len, jnp.dtype(param_dtype).itemsize,
+                        act)
+    return kept, stash_bytes(cfg, eng, seq_len, kept, act)
+
+
 def pipeline_train_loss(cfg: ArchConfig, opts: ModelOptions, eng: EngineConfig,
-                        params, batch):
+                        params, batch, stash_layers: Optional[int] = None):
     """Runs the multi-trial pipelined forward; returns per-trial (loss, aux).
 
     Executes *inside* shard_map. ``params`` leaves are local shards:
     layers (K, L_s, ...), embed/tok (K, V_s, D), head (K, D, V_s), etc.
     batch: tokens/labels (K, M, mb, seq) + optional extras.
+    ``stash_layers``: the layer inputs each tick keeps for its backward;
+    by default ``train_stash``'s for this engine.
     """
     S = eng.n_stages
     K, M = eng.n_trials, eng.n_microbatches
@@ -419,6 +460,23 @@ def pipeline_train_loss(cfg: ArchConfig, opts: ModelOptions, eng: EngineConfig,
     d = cfg.d_model
     cdt = opts.compute_dtype
     pos_train = jnp.broadcast_to(jnp.arange(seq), (mb, seq))
+    kept = stash_layers
+    if kept is None:
+        kept, _ = train_stash(cfg, opts, eng, seq,
+                              jax.tree.leaves(params["layers"])[0].dtype)
+
+    def layer_run(lo, hi):
+        if hi - lo == l_s:
+            return params["layers"]
+        return jax.tree.map(lambda a: a[:, lo:hi], params["layers"])
+
+    # the stage's layers as one scan per run, since a scan keeps the same
+    # residuals in every iteration: the first l_s - kept, then the last
+    # kept, whose inputs each tick keeps. Sliced here, outside the tick
+    # loop, each run's weights are taken for a trial in one slice per tick.
+    runs = [(lo, hi, layer_run(lo, hi), named)
+            for lo, hi, named in ((0, l_s - kept, False),
+                                  (l_s - kept, l_s, True)) if hi > lo]
 
     def embed_slot(slot):
         k, m = _slot_ids(eng, slot)
@@ -441,10 +499,14 @@ def pipeline_train_loss(cfg: ArchConfig, opts: ModelOptions, eng: EngineConfig,
         return pos_train
 
     def tick_compute(x_cur, t):
-        """One tick's compute (embed + stage + head-loss). Rematerialized:
-        only the carried activation is stashed per tick, which bounds the
-        pipeline's activation memory at n_ticks × (mb, seq, d) — the
-        difference between fitting 16 GB HBM and not (see EXPERIMENTS §Perf).
+        """One tick's compute (embed + stage + head-loss). Rematerialized
+        with the stash ``train_stash`` sizes: with per-layer checkpoints
+        (``eng.layer_remat``), the inputs of as many of the stage's last
+        layers as fit in HBM and the stage output are kept per tick, so the
+        backward replays only the other layers before each layer replays
+        itself; with none kept, only the carried activation is,
+        n_ticks × (mb, seq, d) — the difference between fitting 16 GB HBM
+        and not for large models (see EXPERIMENTS §Perf).
         The ppermute stays OUTSIDE so backward replays compute, not comms
         beyond what AD itself requires.
 
@@ -469,15 +531,19 @@ def pipeline_train_loss(cfg: ArchConfig, opts: ModelOptions, eng: EngineConfig,
         x_in = jnp.where(valid_cur, x_in, 0.0).astype(cdt)
 
         def run_stage(x_in):
-            p_layers = _take1(params["layers"], k_cur)
             shared = (_take1(params["shared"], k_cur)
                       if "shared" in params else None)
-            y, _, aux = lm.stack_apply(
-                cfg, opts, p_layers, x_in, pos=slot_pos(slot_cur),
-                mode="train", shared_params=shared, layer_mask=layer_mask,
-                layer_offset=layer_offset, window=0,
-                layer_param_fn=gather_fn, inner_remat=eng.layer_remat)
-            return y, aux
+            y, aux = x_in, 0.0
+            for lo, hi, p_run, named in runs:
+                y, _, aux_run = lm.stack_apply(
+                    cfg, opts, _take1(p_run, k_cur), y,
+                    pos=slot_pos(slot_cur), mode="train",
+                    shared_params=shared, layer_mask=layer_mask[lo:hi],
+                    layer_offset=layer_offset + lo, window=0,
+                    layer_param_fn=gather_fn, inner_remat=eng.layer_remat,
+                    name_inputs=named)
+                aux = aux + aux_run
+            return checkpoint_name(y, STAGE_OUTPUT), aux
 
         if eng.skip_bubbles:
             y, aux = lax.cond(valid_cur, run_stage,
@@ -509,7 +575,13 @@ def pipeline_train_loss(cfg: ArchConfig, opts: ModelOptions, eng: EngineConfig,
         loss_val = jnp.where(valid_out, slot_loss, 0.0)
         return y, loss_val, aux_val
 
-    remat_tick = jax.checkpoint(tick_compute) if opts.remat else tick_compute
+    remat_tick = tick_compute
+    if opts.remat:
+        policy = None
+        if kept:
+            policy = jax.checkpoint_policies.save_only_these_names(
+                lm.LAYER_INPUT, STAGE_OUTPUT)
+        remat_tick = jax.checkpoint(tick_compute, policy=policy)
 
     def tick(carry, t):
         x_cur, loss_acc, aux_acc = carry
@@ -534,6 +606,55 @@ def pipeline_train_loss(cfg: ArchConfig, opts: ModelOptions, eng: EngineConfig,
     # aux was accumulated per stage; total = sum over stages
     aux_acc = lax.psum(aux_acc, eng.stage_axis)
     return loss_acc / M, aux_acc / M
+
+
+def tickwise_train_grads(cfg: ArchConfig, opts: ModelOptions,
+                         eng: EngineConfig, params, batch, objective):
+    """A one-stage gang's gradient, each tick's backward right after its
+    forward; returns (grads, per-trial loss).
+
+    With one stage every tick embeds its own slot, runs the whole stack
+    and takes its own loss, so the step's gradient is the sum of the
+    ticks' gradients, each added into its trial's rows. No activation
+    outlives its tick, so the tick keeps every layer input it can
+    (``train_stash`` with ``eng.stash_ticks`` = 1) and a layer's forward
+    runs twice in a step, not three times. ``objective(loss, aux)`` maps
+    the per-trial (K,) loss and aux to the scalar being differentiated;
+    it must be linear in them, as a sum over ticks. Executes inside
+    shard_map, like ``pipeline_train_loss``.
+    """
+    assert eng.n_stages == 1, "ticks of a pipeline depend on each other"
+    K, M = eng.n_trials, eng.n_microbatches
+    one = dataclasses.replace(eng, n_trials=1, n_microbatches=1)
+    seq = batch["tokens"].shape[-1]
+    kept, _ = train_stash(cfg, opts, eng, seq,
+                          jax.tree.leaves(params["layers"])[0].dtype)
+
+    def tick(carry, t):
+        grads, loss_acc = carry
+        k, m = _slot_ids(eng, t)
+        p_k = jax.tree.map(lambda a: lax.dynamic_slice_in_dim(a, k, 1),
+                           params)
+        b_km = jax.tree.map(lambda a: lax.dynamic_slice_in_dim(
+            lax.dynamic_slice_in_dim(a, k, 1), m, 1, axis=1), batch)
+
+        def slot_objective(p):
+            loss, aux = pipeline_train_loss(cfg, opts, one, p, b_km,
+                                            stash_layers=kept)
+            loss, aux = loss / M, aux / M
+            at_k = jnp.zeros((K,), jnp.float32).at[k]
+            return objective(at_k.set(loss[0]), at_k.set(aux[0])), loss[0]
+
+        g, loss = jax.grad(slot_objective, has_aux=True)(p_k)
+        grads = jax.tree.map(
+            lambda a, gk: lax.dynamic_update_slice_in_dim(
+                a, lax.dynamic_slice_in_dim(a, k, 1) + gk, k, 0), grads, g)
+        return (grads, loss_acc.at[k].add(loss)), None
+
+    zeros = (jax.tree.map(jnp.zeros_like, params),
+             jnp.zeros((K,), jnp.float32))
+    (grads, loss_vec), _ = lax.scan(tick, zeros, jnp.arange(eng.n_slots))
+    return grads, loss_vec
 
 
 # ---------------------------------------------------------------------------
@@ -567,16 +688,24 @@ def make_train_step(cfg: ArchConfig, opts: ModelOptions, eng: EngineConfig,
         # per data-shard microbatch (Switch-style) and averaged.
         dp_degree = eng.data_size * eng.pod_size
 
+        def objective(loss_vec, aux_vec):
+            total = loss_vec.sum()
+            if cfg.moe is not None:
+                total = total + cfg.moe.load_balance_coef * aux_vec.sum()
+            return total / dp_degree
+
         def local_loss(p):
             with jax.named_scope("forward"):
                 loss_vec, aux_vec = pipeline_train_loss(cfg, opts, eng, p,
                                                         batch)
-                total = loss_vec.sum()
-                if cfg.moe is not None:
-                    total = total + cfg.moe.load_balance_coef * aux_vec.sum()
-                return total / dp_degree, loss_vec
+                return objective(loss_vec, aux_vec), loss_vec
 
-        grads, loss_vec = jax.grad(local_loss, has_aux=True)(params)
+        if eng.n_stages == 1:
+            with jax.named_scope("forward"):
+                grads, loss_vec = tickwise_train_grads(
+                    cfg, opts, eng, params, batch, objective)
+        else:
+            grads, loss_vec = jax.grad(local_loss, has_aux=True)(params)
         with jax.named_scope("grad_reduce"):
             grads, gnorm = reduce_grads(cfg, eng, grads)
         with jax.named_scope("optimizer"):

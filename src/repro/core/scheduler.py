@@ -58,8 +58,12 @@ def per_chip_bytes(cfg: ArchConfig, eng: EngineConfig, seq_len: int,
     """Per-chip HBM model for ONE trial under the engine config.
 
     Stage sharding divides layer params by n_stages; FSDP further divides by
-    data_size. The activation stash covers the in-flight pipeline slots
-    (n_ticks live stage-inputs with remat). Optimizer = fp32 m + v + master.
+    data_size. The activation stash is the tick stash (``stash_bytes`` with
+    no layer kept), the least the train step keeps: the layer inputs it may
+    keep besides only fill what the gang leaves of the budget
+    (``stash_layers``), so plans are made with the minimum. Optimizer:
+    ``opt_bytes_per_param`` (12: fp32 m + v + a master copy; this repo's
+    AdamW keeps m and v alone, so 4 B of it is headroom).
     """
     plan = plan_stages(cfg, eng.n_stages)
     layer_p = cfg.layer_param_count() * plan.layers_per_stage
@@ -72,20 +76,82 @@ def per_chip_bytes(cfg: ArchConfig, eng: EngineConfig, seq_len: int,
     params_b = n_params_local * param_bytes
     opt_b = n_params_local * opt_bytes_per_param if train else 0
     if train:
-        # pipeline stash: one stage-input per in-flight tick (remat policy).
-        # Empirically (XLA buffer dump, EXPERIMENTS §Perf) the backward also
-        # materializes an fp32 convert of the whole stash hoisted out of the
-        # loop, so budget bf16 + fp32 = 6 bytes per element.
-        act_b = eng.n_ticks * eng.microbatch * seq_len * cfg.d_model * 6
-        # transient working set: gathered layer weights (×2 for grad), attn
-        # carries, fp32 grad buffers of one layer
-        act_b += 3 * cfg.layer_param_count() * 4
-        act_b += 8 * eng.microbatch * min(seq_len, 4096) * cfg.d_model * 4
+        # every tick's stage input, though a one-stage step holds one
+        act_b = (stash_bytes(cfg, eng, seq_len, 0, ticks=eng.n_ticks)
+                 + _train_transient_bytes(cfg, eng, seq_len))
         cache_b = 0
     else:
         act_b = 4 * eng.microbatch * min(seq_len, 4096) * cfg.d_model * 4
         cache_b = _cache_bytes_per_chip(cfg, eng, seq_len)
     return MemoryEstimate(params_b, opt_b, act_b, cache_b)
+
+
+def _train_transient_bytes(cfg: ArchConfig, eng: EngineConfig,
+                           seq_len: int) -> int:
+    """The train step's transient working set: gathered layer weights (×2
+    for grad), attn carries, fp32 grad buffers of one layer."""
+    return (3 * cfg.layer_param_count() * 4
+            + 8 * eng.microbatch * min(seq_len, 4096) * cfg.d_model * 4)
+
+
+def stash_bytes(cfg: ArchConfig, eng: EngineConfig, seq_len: int,
+                kept_layers: int, act_bytes: int = 2,
+                ticks: Optional[int] = None) -> int:
+    """Per-chip bytes of the train step's activation stash
+    (``pipeline_train_loss``) over ``ticks`` ticks (default
+    ``eng.stash_ticks``, those the step's backward holds at once), one of
+    two schemes per tick:
+
+    * the tick stash (``kept_layers`` 0): each tick keeps its stage input,
+      and its backward replays the whole stage to regain the layers'
+      inputs before each layer replays itself inside its own checkpoint;
+    * the layer stash: each tick also keeps the inputs of its stage's last
+      ``kept_layers`` layers and the stage output (its stage input only
+      while some layer is not kept), and its backward replays only the
+      layers not kept.
+
+    Each is an (mb, seq, d) activation of ``act_bytes``. Empirically (XLA
+    buffer dump, EXPERIMENTS §Perf) the backward also materializes an fp32
+    convert of a narrower stash hoisted out of the loop, so an element
+    below 4 bytes is priced with 4 more (bf16: 6).
+    """
+    layers = plan_stages(cfg, eng.n_stages).layers_per_stage
+    per_tick = kept_layers + 1 + (0 < kept_layers < layers)
+    elem = act_bytes if act_bytes >= 4 else act_bytes + 4
+    ticks = eng.stash_ticks if ticks is None else ticks
+    return ticks * per_tick * eng.microbatch * seq_len * cfg.d_model * elem
+
+
+def stash_layers(cfg: ArchConfig, eng: EngineConfig, seq_len: int,
+                 param_bytes: int = 2, act_bytes: int = 2) -> int:
+    """How many layer inputs each tick of the gang's train step keeps
+    (``pipeline.train_stash``), the rule that picks the stash scheme.
+
+    With ``eng.layer_remat`` (per-layer checkpoints inside the tick's), the
+    most whose stash (``stash_bytes``) fits in ``HBM_BYTES_PER_CHIP ×
+    HBM_BUDGET_FRACTION`` beside what the gang holds anyway, counted once
+    for the gang: K trials' params, optimizer state and gradients (as wide
+    as the params) and the step's transients. None fit: the tick stash.
+    Without per-layer checkpoints a kept input would spare nothing: 0.
+
+    The estimate leaves out what the compiler adds around the gang: bf16
+    copies of float32 weights for the products, one trial's gradient and
+    logits per tick, fragmentation. The optimizer's 4 B of headroom a
+    parameter and the budget's 10% cover them where checked: compiled for
+    a described v5e, the stashes this picks peak within the compiler's
+    limit, float32 and bfloat16 (PERF.md §4).
+    """
+    if not eng.layer_remat:
+        return 0
+    one = per_chip_bytes(cfg, eng, seq_len, train=True,
+                         param_bytes=param_bytes)
+    room = (HBM_BYTES_PER_CHIP * HBM_BUDGET_FRACTION
+            - eng.n_trials * (2 * one.params_bytes + one.opt_bytes)
+            - _train_transient_bytes(cfg, eng, seq_len))
+    layers = plan_stages(cfg, eng.n_stages).layers_per_stage
+    return max((k for k in range(layers + 1)
+                if stash_bytes(cfg, eng, seq_len, k, act_bytes) <= room),
+               default=0)
 
 
 def kv_token_bytes_per_chip(cfg: ArchConfig, eng: EngineConfig) -> int:

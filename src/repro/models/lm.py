@@ -18,6 +18,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from repro.configs.base import ArchConfig
 from repro.models import blocks as B
@@ -252,13 +253,18 @@ def cache_specs(cfg: ArchConfig, batch: int, max_seq: int,
 # Stacked layer application (the unit the pipeline engine runs per stage)
 # ---------------------------------------------------------------------------
 
+# checkpoint name of each layer's input in a rematerialized train stack
+# (``name_inputs``): an enclosing ``jax.checkpoint`` whose policy saves it
+# keeps them, so its backward need not run the stack again to regain them
+LAYER_INPUT = "layer_input"
+
 
 def stack_apply(cfg: ArchConfig, opts: ModelOptions, layer_params, x, *,
                 pos, mode: str = "train", cache=None, shared_params=None,
                 shared_cache=None, layer_mask=None, layer_offset=0,
                 kv_offset=None, window: int = 0, layer_param_fn=None,
-                inner_remat=None, block_tables=None, write_mask=None,
-                q_lens=None):
+                inner_remat=None, name_inputs=False, block_tables=None,
+                write_mask=None, q_lens=None):
     """Apply a contiguous slice of the layer stack.
 
     layer_params: pytree with leading local-layer axis (n_local, ...).
@@ -266,6 +272,9 @@ def stack_apply(cfg: ArchConfig, opts: ModelOptions, layer_params, x, *,
     layer_mask:   (n_local,) bool — False = padded no-op layer.
     layer_param_fn: optional hook applied to each layer's params inside the
         scan body (the pipeline engine uses it for per-layer FSDP all-gather).
+    inner_remat:  checkpoint each layer in train mode (default
+        ``opts.remat``); with ``name_inputs`` each layer's input is then
+        named ``LAYER_INPUT``.
     ``layer_offset`` may be a traced scalar (stage_id * layers_per_stage).
     ``block_tables``/``write_mask`` switch append/decode attention to the
     paged cache layout (cache["layers"] then stacks per-layer block *pools*
@@ -327,7 +336,14 @@ def stack_apply(cfg: ArchConfig, opts: ModelOptions, layer_params, x, *,
         return (y, shc_new, aux + aux_i), c_new
 
     do_remat = opts.remat if inner_remat is None else inner_remat
-    body_fn = jax.checkpoint(body) if (do_remat and mode == "train") else body
+    body_fn = body
+    if do_remat and mode == "train":
+        body_fn = layer_fn = jax.checkpoint(body)
+        if name_inputs:
+            def body_fn(carry, xs):
+                xc, shc, aux = carry
+                return layer_fn((checkpoint_name(xc, LAYER_INPUT), shc, aux),
+                                xs)
     xs = (layer_params, layer_mask, use_shared, site_slot)
     if has_cache:
         xs = xs + (layer_cache,)

@@ -25,6 +25,7 @@ from repro.core import pipeline as pl  # noqa: E402
 from repro.core.hydra import (HydraConfig, HydraRunner,  # noqa: E402
                               run_model_selection)
 from repro.core.partitioner import plan_stages  # noqa: E402
+from repro.core import scheduler as sched  # noqa: E402
 from repro.core.scheduler import GangPlan  # noqa: E402
 from repro.core.trials import grid_search  # noqa: E402
 from repro.data.pipeline import TrainBatches  # noqa: E402
@@ -265,6 +266,21 @@ def test_gang_losses_identical_with_tracer_on_and_off():
     assert {e["program"] for e in compiles} == {"jit(train_step)"}
     assert all(e["span"] == "step.dispatch" and e["seconds"] > 0
                and e["cache_hit"] is False for e in compiles)
+
+
+@pytest.mark.parametrize("hbm,stash,kept", [
+    (sched.HBM_BYTES_PER_CHIP, "layers", 4), (0, "ticks", 0)])
+def test_build_step_span_names_the_remat_stash(monkeypatch, hbm, stash,
+                                               kept):
+    monkeypatch.setattr(sched, "HBM_BYTES_PER_CHIP", hbm)
+    tr = Tracer()
+    runner, gang = _tiny_gang(tr)
+    runner._build(gang)
+    begin = next(e for e in tr.events if e["ev"] == "span_begin"
+                 and e["name"] == "build.step")
+    assert (begin["stash"], begin["stash_layers"]) == (stash, kept)
+    assert begin["stash_bytes"] == sched.stash_bytes(
+        runner.cfg, gang.engine, 16, kept, act_bytes=4) > 0
 
 
 def test_model_selection_spans_nest_rung_gang_build():
